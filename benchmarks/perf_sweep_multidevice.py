@@ -151,6 +151,10 @@ def _inner(out_path: str, reps: int) -> None:
 
 def run(out_path: str = "BENCH_sweep_multidevice.json",
         reps: int = 3) -> dict:
+    # This measures CPU virtual devices only: the child is forced onto
+    # the CPU platform. On a TPU host the meshes run in one process —
+    # `python chip_smoke.py --chips 4` — since a chip belongs to the
+    # one process that first touches it.
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         f" --xla_force_host_platform_device_count="

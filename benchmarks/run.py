@@ -32,6 +32,8 @@ def main(argv=None) -> int:
                     help="session array backend for all sweeps")
     args = ap.parse_args(argv)
 
+    from repro.parallel.jax_compat import use_compile_cache
+    use_compile_cache()
     if args.backend:
         from repro.core.session import SweepSession
         with SweepSession(backend=args.backend):

@@ -24,11 +24,9 @@ The contract each backend provides:
   program-plane event kernel's spine (``repro.core.program_plane``);
 * ``asarray`` / ``to_numpy`` / ``compute_scope()`` — transfer in/out and
   the dtype discipline scope (jax: float64 via x64);
-* ``sa_occupancy(...)`` — the in-program SA PE-occupancy pass
-  (ISSUE 5): the backend-neutral closed form, or on jax optionally the
-  Pallas ``kernels/sa_occupancy.py`` tile kernel
-  (``set_sa_occupancy_impl``) — either way traced, so SA width rides
-  the knob axis;
+* ``sa_occupancy(...)`` — the in-program SA PE-occupancy pass: the
+  backend-neutral closed form, traced on jax, so SA width rides the
+  knob axis;
 * ``psum`` / ``all_gather`` / ``pspec`` / ``shard_map_kernel`` — the
   collective surface the multi-device ``shard_map`` sweep program is
   built from (jax only; resolved through ``parallel.jax_compat``).
@@ -42,9 +40,9 @@ masking stays shape-stable inside the compiled program.
 
 The jax backend requires float64 (the ≤1e-9 record equivalence against
 the numpy oracle is meaningless at f32): entry points run inside
-``compute_scope()`` which enables x64 locally when jax supports the
-scoped switch, and otherwise raises a clear error telling the caller to
-enable ``jax_enable_x64`` globally.
+``compute_scope()``, which enables x64 locally through
+``parallel.jax_compat.enable_x64`` and raises a clear error if arrays
+still come out narrower.
 """
 from __future__ import annotations
 
@@ -56,11 +54,11 @@ import numpy as np
 from repro.core import session
 
 _X64_HELP = (
-    "the jax sweep backend requires float64 (x64). Enable it globally "
-    "before jax is first used — `import jax; "
-    "jax.config.update('jax_enable_x64', True)` or set the environment "
-    "variable JAX_ENABLE_X64=1 — or upgrade to a jax with the scoped "
-    "`jax.experimental.enable_x64` context manager."
+    "the jax sweep backend requires float64 (x64), but arrays inside "
+    "its compute scope are not float64. The scope switches x64 on with "
+    "`jax.enable_x64(True)` (repro.parallel.jax_compat.enable_x64); "
+    "make sure nothing inside it turns x64 off, or enable it for the "
+    "whole process with JAX_ENABLE_X64=1."
 )
 
 
@@ -78,7 +76,6 @@ class NumpyBackend:
 
     name = "numpy"
     xp = np
-    sa_occupancy_impl = "xp"
 
     @staticmethod
     def sa_occupancy(mm_m, mm_k, mm_n, saw, weight_load_cycles=None):
@@ -156,22 +153,6 @@ class JaxBackend:
                 "backend='numpy' or install jax") from e
         self._jax = jax
         self.xp = jnp
-        try:
-            from jax.experimental import enable_x64
-            self._x64_ctx: Optional[Callable] = enable_x64
-        except ImportError:  # pragma: no cover - future jax drift
-            self._x64_ctx = None
-
-    @property
-    def sa_occupancy_impl(self) -> str:
-        """SA occupancy pass inside the jitted sweep kernel: "jnp" (the
-        pure-jnp closed form, the oracle) or "pallas" (the
-        kernels/sa_occupancy.py tile kernel, interpret=True on CPU).
-        Session-scoped state (``repro.core.session``): switch via
-        ``set_sa_occupancy_impl`` or ``SweepSession(sa_occupancy_impl=)``;
-        the sweep kernel cache keys on it so flipping recompiles
-        cleanly."""
-        return session.resolve("sa_occupancy_impl")
 
     # -- x64 discipline ------------------------------------------------
     def x64_enabled(self) -> bool:
@@ -181,15 +162,11 @@ class JaxBackend:
     def compute_scope(self):
         """All transfers, traces, and executions of the jax sweep path
         run inside this scope so arrays stay float64 end-to-end."""
-        if self.x64_enabled():
+        from repro.parallel import jax_compat
+        with jax_compat.enable_x64():
+            if not self.x64_enabled():
+                raise RuntimeError(_X64_HELP)
             yield
-        elif self._x64_ctx is not None:
-            with self._x64_ctx():
-                if not self.x64_enabled():  # pragma: no cover
-                    raise RuntimeError(_X64_HELP)
-                yield
-        else:
-            raise RuntimeError(_X64_HELP)
 
     # -- array contract ------------------------------------------------
     def asarray(self, x):
@@ -225,12 +202,7 @@ class JaxBackend:
     def sa_occupancy(self, mm_m, mm_k, mm_n, saw, weight_load_cycles=None):
         """Per-op SA PE-occupancy stats, computed *inside* the traced
         sweep program (``saw`` may be a traced scalar — the SA-width
-        knob axis). Routes to the pure-jnp closed form or the Pallas
-        tile kernel per ``sa_occupancy_impl``."""
-        if self.sa_occupancy_impl == "pallas":
-            from repro.kernels.sa_occupancy import sa_occupancy_p
-            return sa_occupancy_p(mm_m, mm_k, mm_n, saw,
-                                  weight_load_cycles)
+        knob axis), through the backend-neutral closed form."""
         from repro.core.sa_gating import gating_stats_batch_xp
         return gating_stats_batch_xp(mm_m, mm_k, mm_n, saw,
                                      weight_load_cycles, xp=self.xp)
@@ -284,10 +256,10 @@ class JaxBackend:
 
     def shard_map_kernel(self, body: Callable, mesh, in_specs,
                          out_specs) -> Callable:
-        """Compile ``body`` as one SPMD program over ``mesh`` via the
-        version-spanning ``jax_compat.shard_map`` (replication checks
-        off: the kernel's psums make every unmentioned-axis output
-        genuinely replicated)."""
+        """Compile ``body`` as one SPMD program over ``mesh`` via
+        ``jax_compat.shard_map`` (replication checks off: the kernel's
+        psums make every unmentioned-axis output genuinely
+        replicated)."""
         from repro.parallel import jax_compat
         return self._jax.jit(jax_compat.shard_map(
             body, mesh=mesh, in_specs=in_specs, out_specs=out_specs))
@@ -361,24 +333,6 @@ def failover_rungs(name: Optional[str] = None, jax_mesh=None) \
     rungs.append(("jax", None))
     rungs.append(("numpy", None))
     return tuple(rungs)
-
-
-SA_OCCUPANCY_IMPLS = ("jnp", "pallas")
-
-
-def set_sa_occupancy_impl(name: str) -> str:
-    """Select the jax backend's in-program SA occupancy pass: ``"jnp"``
-    (pure-jnp closed form, the default and oracle) or ``"pallas"`` (the
-    ``kernels/sa_occupancy.py`` tile kernel, interpret-mode on CPU).
-    Returns the previous selection. The sweep-kernel cache keys on this,
-    so flipping it mid-session recompiles instead of reusing a stale
-    program."""
-    if name not in SA_OCCUPANCY_IMPLS:
-        raise KeyError(f"unknown sa_occupancy impl {name!r}; "
-                       f"have {SA_OCCUPANCY_IMPLS}")
-    prev = session.resolve("sa_occupancy_impl")
-    session.set_root(sa_occupancy_impl=name)
-    return prev
 
 
 # --------------------------------------------------------------------------

@@ -1563,28 +1563,27 @@ def _sweep_kernel(data, knobs, policies, bk, wl_axis=None, knob_axis=None):
             "sram_GU": base["sram_GU"], "sram_dyn": base["sram_dyn"]}
 
 
-# jitted sweep kernels cached per (backend, occupancy impl): the jax
-# program compiles once per (stack shape, knob count, policies) and is
-# reused across NPU generations and repeated sweeps
-_KERNELS: dict[tuple, object] = {}
+# jitted sweep kernels cached per backend: the jax program compiles
+# once per (stack shape, knob count, policies) and is reused across NPU
+# generations and repeated sweeps
+_KERNELS: dict[str, object] = {}
 
 
 def _backend_kernel(bk):
     """The (possibly jitted) single-device sweep kernel for one
-    backend + occupancy-impl selection."""
-    key = (bk.name, bk.sa_occupancy_impl)
-    fn = _KERNELS.get(key)
+    backend."""
+    fn = _KERNELS.get(bk.name)
     if fn is None:
         def kern(data, knobs, policies):
             return _sweep_kernel(data, knobs, policies, bk)
         fn = bk.jit(kern, static_argnames=("policies",))
-        _KERNELS[key] = fn
+        _KERNELS[bk.name] = fn
     return fn
 
 
-# shard_map sweep programs, keyed by (backend, occupancy impl, mesh
-# identity, policies, axes); the value keeps a strong ref to the mesh
-# so its id cannot be reused while the entry lives
+# shard_map sweep programs, keyed by (backend, mesh identity, policies,
+# axes); the value keeps a strong ref to the mesh so its id cannot be
+# reused while the entry lives
 _SHARD_KERNELS: dict[tuple, tuple] = {}
 
 
@@ -1594,8 +1593,7 @@ def _shard_kernel(bk, mesh, policies, wl_axis, knob_axis):
     pairs and the knob grid sharded over ``knob_axis``; everything
     else replicated. Inputs must be padded to the axis sizes
     (``_sharded_backend_data`` / ``_knob_arrays(pad_to=...)``)."""
-    key = (bk.name, bk.sa_occupancy_impl, id(mesh), policies,
-           wl_axis, knob_axis)
+    key = (bk.name, id(mesh), policies, wl_axis, knob_axis)
     hit = _SHARD_KERNELS.get(key)
     if hit is not None and hit[0] is mesh:
         return hit[1]
@@ -1879,10 +1877,11 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
     wl_size = knob_size = 1
     if mesh is not None:
         sizes = bk.mesh_axis_sizes(mesh)
+        wl_size = sizes.get("wl", 1)
         if "knob" in sizes:
             knob_axis, knob_size = "knob", sizes["knob"]
             if "wl" in sizes:
-                wl_axis, wl_size = "wl", sizes["wl"]
+                wl_axis = "wl"
     with bk.compute_scope():
         for ai, npu in enumerate(npu_specs):
             if knob_axis is not None:
@@ -1894,8 +1893,13 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                                      knob_axis)
                 vm = bk.block(kern(data, knobs))
             else:
-                data, sram_setpm = _backend_data(st, npu, bk)
-                if mesh is not None:
+                if mesh is None:
+                    data, sram_setpm = _backend_data(st, npu, bk)
+                else:
+                    # the op axis must divide the "wl" axis: pad it
+                    # with inert ops, exactly as the shard_map path does
+                    data, sram_setpm = _sharded_backend_data(
+                        st, npu, bk, wl_size)
                     data = bk.shard_data(data, mesh)
                 knobs = _knob_arrays(knob_grid, npu, bk)
                 kern = _backend_kernel(bk)

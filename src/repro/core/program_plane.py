@@ -394,28 +394,12 @@ class ProgramPlaneBatch:
         return recs
 
 
-def program_plane_batch(workloads: Sequence[Workload] | Workload,
-                        npus: Iterable[NPUSpec | str] = ("NPU-D",),
-                        knob_grid: Optional[Sequence[PolicyKnobs]] = None,
-                        backend: Optional[str] = None,
-                        jax_mesh=None) -> ProgramPlaneBatch:
-    """Evaluate the program plane for every (workload, npu, knob) cell
-    through the batched executor kernel + the closed-form folds.
-
-    Matches the per-cell ``lowering.crossval_record`` record-for-record:
-    executor integers exactly, closed-form folds bit-identically (same
-    host functions), the policy side within ``evaluate_batch``'s
-    documented <=1e-9 of per-cell ``evaluate``."""
-    if isinstance(workloads, Workload):
-        workloads = [workloads]
-    workloads = list(workloads)
-    npu_specs = [get_npu(n) if isinstance(n, str) else n for n in npus]
-    grid = tuple(knob_grid) if knob_grid is not None else (PolicyKnobs(),)
-    bk = get_backend(backend)
-    if jax_mesh is None and bk.name == "jax":
-        jax_mesh = session.resolve("jax_mesh")
-
-    triples, inv = knob_pairs(grid)
+def _exec_rows(workloads: Sequence[Workload],
+               npu_specs: Sequence[NPUSpec], triples: list[tuple]) \
+        -> tuple[ProgramArrays, np.ndarray, dict]:
+    """The executor rows of a (workload x npu x knob-triple) cube, row
+    ``(wi * A + ai) * T + ti``: the ragged event stack, each row's
+    stream, and the dense kernel input."""
     w_n, a_n, t_n = len(workloads), len(npu_specs), len(triples)
 
     # one lowered program per (workload, effective npu); one event
@@ -448,7 +432,34 @@ def program_plane_batch(workloads: Sequence[Workload] | Workload,
                     window[ri, ui] = scaled_window(g, key, dsc, wsc)
 
     pa = build_program_arrays(progs, dscales)
-    data = _pack_dense(pa, stream_of_row, window, delay, horizon)
+    return pa, stream_of_row, _pack_dense(pa, stream_of_row, window,
+                                          delay, horizon)
+
+
+def program_plane_batch(workloads: Sequence[Workload] | Workload,
+                        npus: Iterable[NPUSpec | str] = ("NPU-D",),
+                        knob_grid: Optional[Sequence[PolicyKnobs]] = None,
+                        backend: Optional[str] = None,
+                        jax_mesh=None) -> ProgramPlaneBatch:
+    """Evaluate the program plane for every (workload, npu, knob) cell
+    through the batched executor kernel + the closed-form folds.
+
+    Matches the per-cell ``lowering.crossval_record`` record-for-record:
+    executor integers exactly, closed-form folds bit-identically (same
+    host functions), the policy side within ``evaluate_batch``'s
+    documented <=1e-9 of per-cell ``evaluate``."""
+    if isinstance(workloads, Workload):
+        workloads = [workloads]
+    workloads = list(workloads)
+    npu_specs = [get_npu(n) if isinstance(n, str) else n for n in npus]
+    grid = tuple(knob_grid) if knob_grid is not None else (PolicyKnobs(),)
+    bk = get_backend(backend)
+    if jax_mesh is None and bk.name == "jax":
+        jax_mesh = session.resolve("jax_mesh")
+
+    triples, inv = knob_pairs(grid)
+    w_n, a_n, t_n = len(workloads), len(npu_specs), len(triples)
+    pa, stream_of_row, data = _exec_rows(workloads, npu_specs, triples)
     if jax_mesh is not None and bk.name == "jax" \
             and "wl" in bk.mesh_axis_sizes(jax_mesh):
         out = _run_kernel_mesh(data, bk, jax_mesh)

@@ -31,8 +31,7 @@ Implementations (fastest first):
     forms against it.
 
 The prefix-sum row/col logic (paper Fig 12) is ``prefix_on_bitmap`` and is
-shared by the Pallas ``gated_matmul`` / ``sa_occupancy`` kernels'
-tile-level analogues.
+shared by the Pallas ``gated_matmul`` kernel's tile-level analogue.
 """
 from __future__ import annotations
 

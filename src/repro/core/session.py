@@ -1,9 +1,8 @@
 """Session-scoped sweep configuration (ISSUE 7).
 
-The sweep substrate used to be configured through four independent
+The sweep substrate used to be configured through independent
 module-level switches threaded ad hoc through every entry point:
 ``backend.set_default_backend`` (what ``backend=None`` resolves to),
-``backend.set_sa_occupancy_impl`` (the jax kernel's occupancy pass),
 a ``jax_mesh=`` kwarg repeated on each call, and
 ``sa_gating.set_gating_cache_size``. ``SweepSession`` consolidates them
 into one context object::
@@ -20,11 +19,11 @@ previous state on exit, exception-safe.
 
 Compatibility contract:
 
-* ``backend.default_backend()`` / ``backend.set_default_backend`` and
-  ``backend.set_sa_occupancy_impl`` now read/write the ROOT session, so
-  old call sites keep working; while a session that pins the same field
-  is active, the session wins (the setter still records the new root
-  default, visible once the session exits).
+* ``backend.default_backend()`` / ``backend.set_default_backend`` now
+  read/write the ROOT session, so old call sites keep working; while a
+  session that pins the same field is active, the session wins (the
+  setter still records the new root default, visible once the session
+  exits).
 * ``gating_cache_size`` is applied on ``__enter__`` via
   ``sa_gating.set_gating_cache_size`` (the LRU itself stays the single
   source of truth) and the previous size is restored on ``__exit__``.
@@ -54,16 +53,14 @@ class _Unset:
 
 UNSET = _Unset()
 
-_FIELDS = ("backend", "jax_mesh", "sa_occupancy_impl",
-           "gating_cache_size", "guard")
+_FIELDS = ("backend", "jax_mesh", "gating_cache_size", "guard")
 
 
 class SweepSession:
     """One configuration layer for the sweep substrate.
 
     Parameters all default to ``UNSET`` (inherit). ``backend`` must be
-    one of ``backend.BACKEND_NAMES``; ``sa_occupancy_impl`` one of
-    ``backend.SA_OCCUPANCY_IMPLS``; ``gating_cache_size`` a cache size
+    one of ``backend.BACKEND_NAMES``; ``gating_cache_size`` a cache size
     accepted by ``sa_gating.set_gating_cache_size`` (``None`` =
     unbounded); ``guard`` a ``guard.GuardPolicy`` (or ``None``) that
     campaign entry points (``sweep_fleet`` / ``sweep_chaos``) pick up
@@ -74,17 +71,13 @@ class SweepSession:
     """
 
     def __init__(self, backend: Any = UNSET, jax_mesh: Any = UNSET,
-                 sa_occupancy_impl: Any = UNSET,
                  gating_cache_size: Any = UNSET, guard: Any = UNSET):
         if backend is not UNSET:
             _check_backend(backend)
-        if sa_occupancy_impl is not UNSET:
-            _check_impl(sa_occupancy_impl)
         if guard is not UNSET:
             _check_guard(guard)
         self.backend = backend
         self.jax_mesh = jax_mesh
-        self.sa_occupancy_impl = sa_occupancy_impl
         self.gating_cache_size = gating_cache_size
         self.guard = guard
         self._active = False
@@ -130,14 +123,6 @@ def _check_backend(name: str) -> str:
     return name
 
 
-def _check_impl(name: str) -> str:
-    from repro.core.backend import SA_OCCUPANCY_IMPLS
-    if name not in SA_OCCUPANCY_IMPLS:
-        raise KeyError(f"unknown sa_occupancy impl {name!r}; "
-                       f"have {SA_OCCUPANCY_IMPLS}")
-    return name
-
-
 def _check_guard(value: Any) -> Any:
     from repro.core.guard import GuardPolicy
     if value is not None and not isinstance(value, GuardPolicy):
@@ -162,7 +147,6 @@ def _root() -> SweepSession:
     s = object.__new__(SweepSession)
     s.backend = "numpy"
     s.jax_mesh = None
-    s.sa_occupancy_impl = "jnp"
     s.gating_cache_size = UNSET
     s.guard = None
     s._active = True  # the root never exits
@@ -214,8 +198,6 @@ def set_root(**fields: Any) -> dict:
                            f"have {_FIELDS}")
         if name == "backend":
             _check_backend(value)
-        elif name == "sa_occupancy_impl":
-            _check_impl(value)
         elif name == "guard":
             _check_guard(value)
         prev[name] = getattr(_ROOT, name)

@@ -1,8 +1,6 @@
 """Pallas TPU kernels (interpret=True on CPU) + jnp oracles.
 
 gated_matmul     — zero-tile skipping (the paper's SA gating, TPU-native)
-sa_occupancy     — per-op SA PE-occupancy closed form (the sweep plane's
-                   on-device ``gating_stats_batch``; traced SA width)
 flash_attention  — causal block-skipping online-softmax attention
 ssd_scan         — chunked SSD with VMEM-carried state
 decode_attention — single-token attention, cache_len block skipping

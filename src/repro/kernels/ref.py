@@ -5,17 +5,6 @@ import jax
 import jax.numpy as jnp
 
 
-def ref_sa_occupancy(mm_m, mm_k, mm_n, saw, weight_load_cycles=None) \
-        -> dict:
-    """Pure-jnp SA PE-occupancy closed form — the oracle for the Pallas
-    ``sa_occupancy`` kernel, and the default on-device occupancy pass of
-    the jax sweep backend. Delegates to the backend-neutral
-    ``core.sa_gating.gating_stats_batch_xp`` with ``xp=jnp``."""
-    from repro.core.sa_gating import gating_stats_batch_xp
-    return gating_stats_batch_xp(mm_m, mm_k, mm_n, saw,
-                                 weight_load_cycles, xp=jnp)
-
-
 def ref_matmul(x: jax.Array, w: jax.Array) -> jax.Array:
     """[M,K] x [K,N] in f32 accumulation."""
     return jnp.dot(x.astype(jnp.float32),

@@ -28,7 +28,7 @@ from repro.configs.base import SHAPES, ArchConfig, ShapeConfig, get_arch, \
 from repro.core.roofline import model_flops_estimate, report_from_hlo
 from repro.data.specs import batch_specs
 from repro.launch.mesh import make_production_mesh, mesh_desc, n_chips
-from repro.parallel.jax_compat import set_mesh
+from repro.parallel.jax_compat import set_mesh, use_compile_cache
 from repro.models import model as M
 from repro.models import registry
 from repro.models.param import is_spec, tree_sds
@@ -350,6 +350,7 @@ def main(argv=None):
     ap.add_argument("--tag", default="")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cells: list[tuple[str, str]] = []
     archs = [args.arch] if args.arch else list_archs()

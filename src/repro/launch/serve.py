@@ -27,6 +27,7 @@ from repro.configs.base import ShapeConfig, get_arch
 from repro.models import model as M
 from repro.models import registry
 from repro.models.param import init_params
+from repro.parallel.jax_compat import use_compile_cache
 from repro.parallel.sharding import BASELINE, use_rules
 from repro.train.steps import make_prefill_step, make_serve_step
 
@@ -177,6 +178,7 @@ def main(argv=None):
                          "path (atomic JSON; also written when a "
                          "SIGTERM/SIGINT drain ends the run early)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     with use_rules(BASELINE):
         srv = Server(args.arch, batch=args.batch,
                      max_seq=args.prompt_len + args.tokens + 8)

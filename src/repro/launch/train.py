@@ -36,6 +36,7 @@ from repro.data.pipeline import SyntheticDataset
 from repro.models import registry
 from repro.models.param import init_params
 from repro.optim.adamw import AdamWConfig
+from repro.parallel.jax_compat import use_compile_cache
 from repro.parallel.sharding import BASELINE, RULE_VARIANTS, use_rules
 from repro.train.steps import TrainState, make_train_step
 
@@ -136,6 +137,7 @@ def main(argv=None):
               "checkpoint_every", "seed", "fail_at_step"):
         ap.add_argument(f"--{f.replace('_', '-')}", type=int, default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
     cfg = TrainLoopConfig()
     for k, v in vars(args).items():
         if v is not None:

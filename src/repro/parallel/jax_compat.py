@@ -1,87 +1,58 @@
-"""Version-compat shims for the jax mesh/sharding API drift.
+"""The one place for jax APIs that drift between releases.
 
-The execution plane targets the post-0.5 "explicit mesh" API
-(``jax.sharding.AxisType``, ``jax.sharding.get_abstract_mesh``,
-``jax.set_mesh``, top-level ``jax.shard_map``).  The pinned CI floor is
-jax 0.4.37, where none of those exist yet: meshes carry no axis types,
-the active mesh lives in the pjit resource env
-(``thread_resources.env.physical_mesh``), and ``shard_map`` sits in
-``jax.experimental`` with ``check_rep`` instead of ``check_vma``.
+The repo targets the installed jax (0.9). Every spelling of the mesh,
+sharding, x64 and compile-cache surface that has moved before funnels
+through this module, so the model, launch and sweep code never spells
+them directly:
 
-Everything the repo needs from that surface funnels through this module
-so model/launch code stays version-agnostic:
-
-* ``make_mesh(shape, axes)``        — ``axis_types`` when supported;
+* ``make_mesh(shape, axes)``        — Auto axis types on every axis;
 * ``set_mesh(mesh)``                — context manager activating a mesh
-  for GSPMD sharding constraints (``jax.set_mesh`` or legacy
-  ``with mesh:`` resource env);
-* ``get_abstract_mesh()``           — the active mesh or ``None``
-  (never raises, unlike the drifting attribute lookups);
-* ``mesh_axis_sizes(mesh)``         — ``{axis: size}`` for either a new
-  AbstractMesh or a legacy physical Mesh;
-* ``shard_map(...)``                — replication-check kwarg spelled
-  per version.
+  for GSPMD sharding constraints;
+* ``get_abstract_mesh()``           — the active mesh or ``None``;
+* ``mesh_axis_sizes(mesh)``         — ``{axis: size}`` for abstract and
+  physical meshes alike;
+* ``shard_map(...)``                — the replication-check kwarg;
+* ``enable_x64()``                  — scoped float64/int64 (the sweep
+  and program-plane kernels run entirely under it);
+* ``use_compile_cache()``           — the persistent compile-cache rule
+  of the entry points.
 """
 from __future__ import annotations
 
 import contextlib
+import os
+from pathlib import Path
 from typing import Optional
 
 import jax
 
-# ----------------------------------------------------------------------
-# feature detection (done once at import; cheap attribute probes only)
-# ----------------------------------------------------------------------
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-HAS_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_TOPLEVEL_SHARD_MAP = hasattr(jax, "shard_map")
+# <repo>/.jax_cache: a fixed path, so a later process finds what an
+# earlier one compiled (the path is part of the cache key)
+REPO_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` with Auto axis types when the API has them."""
-    kwargs = {}
+    """``jax.make_mesh`` with Auto axis types."""
+    kwargs = {"axis_types": (jax.sharding.AxisType.Auto,) * len(axes)}
     if devices is not None:
         kwargs["devices"] = devices
-    if HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
     return jax.make_mesh(shape, axes, **kwargs)
 
 
 @contextlib.contextmanager
 def set_mesh(mesh):
-    """Activate ``mesh`` for sharding constraints inside jit.
-
-    New jax: ``jax.set_mesh`` (abstract-mesh context). Old jax: enter the
-    mesh's own context manager, which installs it in the pjit resource
-    env — ``with_sharding_constraint`` then accepts bare PartitionSpecs.
-    """
-    if HAS_SET_MESH:
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Activate ``mesh`` for sharding constraints inside jit."""
+    with jax.set_mesh(mesh):
+        yield mesh
 
 
 def get_abstract_mesh() -> Optional[object]:
     """The mesh active for GSPMD lowering, or ``None`` when unset/empty.
 
     Callers treat ``None`` as "single device, skip constraints", which
-    keeps smoke tests mesh-free on every jax version.
+    keeps smoke tests mesh-free.
     """
-    if HAS_ABSTRACT_MESH:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or mesh.empty:
-            return None
-        return mesh
-    # 0.4.x: the active mesh is the resource-env physical mesh
-    try:
-        from jax._src import mesh as mesh_lib
-        mesh = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - internal layout drift
-        return None
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty:
         return None
     return mesh
@@ -91,21 +62,35 @@ def mesh_axis_sizes(mesh) -> dict[str, int]:
     """``{axis_name: size}`` for abstract and physical meshes alike."""
     if mesh is None:
         return {}
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(mesh.axis_names, sizes))
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` with the replication/varying-manual-axes check kwarg
-    spelled for the running jax (``check_vma`` new, ``check_rep`` old)."""
-    if HAS_TOPLEVEL_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` with the varying-manual-axes check switched by
+    ``check``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
+
+
+def enable_x64():
+    """Context manager: float64/int64 jax arrays inside, the process
+    setting restored on exit."""
+    return jax.enable_x64(True)
+
+
+def use_compile_cache() -> Optional[str]:
+    """Point jax's persistent compile cache at a fixed directory.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    this leaves the setting alone. Otherwise the cache goes to
+    ``<repo>/.jax_cache`` (listed in ``.gitignore``). Returns the
+    directory in use. Entry points call this once, before their first
+    compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(REPO_COMPILE_CACHE))
+    return str(REPO_COMPILE_CACHE)
 
 
 def sweep_mesh(wl: int = 1, knob: int = 1, *, devices=None):

@@ -37,14 +37,6 @@ KNOB_GRID = [
 ]
 
 
-def _require_x64():
-    bk = get_backend("jax")
-    if bk._x64_ctx is None and not bk.x64_enabled():
-        pytest.skip("this jax has no scoped x64 switch and "
-                    "jax_enable_x64 is off")
-    return bk
-
-
 # --------------------------------------------------------------------------
 # acceptance grid: suite × 5 NPUs × 5 policies × 4 knobs
 # --------------------------------------------------------------------------
@@ -52,7 +44,6 @@ def _require_x64():
 def test_full_grid_matches_numpy_batched():
     """The ISSUE-4 acceptance grid, record-for-record ≤1e-9 with
     byte-identical ordering against the numpy batched path."""
-    _require_x64()
     suite = paper_suite()
     npus = tuple(NPUS)
     ref = sweep(suite, npus, POLICIES, KNOB_GRID, backend="numpy")
@@ -66,7 +57,6 @@ def test_full_grid_matches_numpy_batched():
 def test_matches_sweep_reference_loop_oracle():
     """Transitively through the numpy plane is not enough: hold the jax
     backend directly to the original one-evaluate-per-cell loop."""
-    _require_x64()
     wls = paper_suite()[:3]
     grid = [PolicyKnobs(), PolicyKnobs(delay_scale=4.0)]
     ref = sweep_reference(wls, ("NPU-B", "NPU-E"), POLICIES, grid)
@@ -105,7 +95,6 @@ def test_randomized_ragged_stack_property():
     """Random ragged stack with empty and single-op workloads mixed in:
     the jax backend must match per-workload ``evaluate`` cell-for-cell
     (and the empty segments must come back as exact zeros)."""
-    _require_x64()
     rng = np.random.default_rng(11)
     sizes = [0, 1, int(rng.integers(2, 30)), 0, 1,
              int(rng.integers(2, 30)), int(rng.integers(2, 30)), 0]
@@ -132,7 +121,6 @@ def test_randomized_ragged_stack_property():
 
 
 def test_knob_grid_of_size_one_and_single_workload():
-    _require_x64()
     wl = paper_suite()[8]
     ref = sweep(wl, ("NPU-C",), POLICIES,
                 [PolicyKnobs(delay_scale=2.0)], backend="numpy")
@@ -143,7 +131,6 @@ def test_knob_grid_of_size_one_and_single_workload():
 
 
 def test_no_workloads_empty_result():
-    _require_x64()
     res = evaluate_batch([], ("NPU-D",), POLICIES, backend="jax")
     assert res.shape == (0, 1, len(POLICIES), 1)
     assert res.records() == []
@@ -156,7 +143,6 @@ def test_no_workloads_empty_result():
 def test_sweep_grid_cross_product_equivalence():
     """A small §6.5 cross product: jax matches numpy record-for-record
     and the knob metadata columns carry the delay-major ordering."""
-    _require_x64()
     wls = paper_suite()[:2]
     kw = dict(delay_scale=(0.5, 1.0, 2.0),
               leak_off_logic=(0.03, 0.2),
@@ -179,7 +165,6 @@ def test_sweep_grid_sa_width_axis():
     the traced-saw jax kernel matches a direct evaluation on a
     width-replaced spec, and a non-native width genuinely changes the
     SA numbers."""
-    _require_x64()
     wl = paper_suite()[4]  # prefill, SA-heavy
     res = sweep_grid(wl, ("NPU-D",), ("NoPG", "ReGate-HW"),
                      sa_width=(None, 256), backend="jax",
@@ -209,7 +194,6 @@ def test_sa_width_knob_traced_vs_loop_oracle():
     """A width × delay grid through the jax kernel against the
     per-cell loop oracle (``sweep_reference``), which resolves widths
     through memoized ``hw.with_sa_width`` variant specs."""
-    _require_x64()
     from repro.core.sweep import knob_product
     wls = paper_suite()[:2]
     grid = knob_product(delay_scale=(1.0, 3.0),
@@ -219,32 +203,11 @@ def test_sa_width_knob_traced_vs_loop_oracle():
     _assert_records_match(ref, got)
 
 
-def test_pallas_occupancy_inside_sweep():
-    """The Pallas ``sa_occupancy`` kernel, selected through the backend
-    contract, reproduces the numpy sweep record-for-record (the
-    ROADMAP's "whole jax sweep program stays on-device" step)."""
-    _require_x64()
-    from repro.core import backend as backend_mod
-    from repro.core.sweep import knob_product
-    wl = paper_suite()[4]
-    grid = knob_product(delay_scale=(1.0, 2.0), sa_width=(None, 256))
-    ref = sweep(wl, ("NPU-D",), POLICIES, grid, backend="numpy")
-    prev = backend_mod.set_sa_occupancy_impl("pallas")
-    try:
-        got = sweep(wl, ("NPU-D",), POLICIES, grid, backend="jax")
-    finally:
-        backend_mod.set_sa_occupancy_impl(prev)
-    _assert_records_match(ref, got)
-    with pytest.raises(KeyError):
-        backend_mod.set_sa_occupancy_impl("nope")
-
-
 # --------------------------------------------------------------------------
 # sharding over the stacked workload axis (jax_compat mesh)
 # --------------------------------------------------------------------------
 
 def test_jax_mesh_sharded_matches_unsharded():
-    _require_x64()
     from repro.parallel import jax_compat
     mesh = jax_compat.make_mesh((len(jax.devices()),), ("wl",))
     wls = paper_suite()[:3]
@@ -268,7 +231,6 @@ def test_shard_map_mesh_matches_numpy(axes):
     sharded over ``knob``); every topology must match the numpy oracle
     record-for-record — including knob/pair counts that do not divide
     the axis size (the padding path)."""
-    _require_x64()
     from repro.core.sweep import knob_product
     from repro.parallel import jax_compat
     n_dev = len(jax.devices())
@@ -290,22 +252,56 @@ def test_shard_map_mesh_matches_numpy(axes):
 # --------------------------------------------------------------------------
 
 def test_x64_disabled_raises_clear_error(monkeypatch):
-    """Without a scoped x64 switch and with the global flag off, the
-    jax backend must refuse loudly (f32 would silently violate the
-    ≤1e-9 contract) and tell the user how to enable x64."""
-    bk = get_backend("jax")
-    monkeypatch.setattr(bk, "_x64_ctx", None)
-    if bk.x64_enabled():
-        pytest.skip("jax_enable_x64 is globally on in this session")
+    """If the compute scope's x64 switch fails to take effect, the jax
+    backend must refuse loudly (f32 would silently violate the ≤1e-9
+    contract) and tell the user how to enable x64."""
+    from repro.parallel import jax_compat
+    monkeypatch.setattr(jax_compat, "enable_x64",
+                        lambda: jax.enable_x64(False))
     with pytest.raises(RuntimeError, match="x64"):
         evaluate_batch(paper_suite()[:1], ("NPU-D",), ("NoPG",),
                        backend="jax")
 
 
+def test_compute_scope_yields_float64():
+    """On the installed jax the scope switches x64 on by itself and
+    restores the process setting on exit."""
+    bk = get_backend("jax")
+    outside = bk.x64_enabled()
+    with bk.compute_scope():
+        assert bk.asarray(np.zeros(3)).dtype == np.float64
+        assert bk.xp.asarray(1.5).dtype == np.float64
+        assert bk.xp.arange(3).dtype == np.int64
+    assert bk.x64_enabled() == outside
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_rule(monkeypatch, tmp_path, env_set):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to jax; without it
+    the cache goes to the fixed ``<repo>/.jax_cache``."""
+    from pathlib import Path
+
+    from repro.parallel import jax_compat
+    before = jax.config.jax_compilation_cache_dir
+    repo_cache = Path(__file__).resolve().parents[1] / ".jax_cache"
+    try:
+        if env_set:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert jax_compat.use_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            got = jax_compat.use_compile_cache()
+            assert got == str(repo_cache)
+            assert jax.config.jax_compilation_cache_dir == got
+            assert jax_compat.use_compile_cache() == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
 def test_default_backend_steering(monkeypatch):
     """``set_default_backend`` steers ``backend=None`` callers (what
     ``benchmarks/run.py --backend jax`` relies on)."""
-    _require_x64()
     from repro.core import backend as backend_mod
     wl = paper_suite()[0]
     ref = sweep(wl, policies=("NoPG",), backend="numpy")

@@ -150,75 +150,3 @@ def test_decode_attention_kernel(case):
     s = jnp.where(jnp.arange(S)[None, :] <= clen, s, -1e30)
     ref = jnp.einsum("bk,bkd->bd", jax.nn.softmax(s, -1), vc)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
-
-
-# ------------------------------------------------------------ sa occupancy
-SA_OCC_CASES = [
-    # (n_ops, saw, block) — non-multiple n exercises the pad path
-    (777, 128.0, 512),
-    (64, 256.0, 64),
-    (1, 8.0, 512),
-    (513, 1.0, 256),
-]
-
-
-@pytest.mark.parametrize("case", SA_OCC_CASES)
-def test_sa_occupancy_kernel_matches_oracle(case):
-    """Pallas closed-form occupancy kernel vs the jnp oracle, exact
-    (both evaluate the same integer-valued float64 math)."""
-    from repro.core.sa_gating import gating_stats_batch
-    from repro.kernels.sa_occupancy import sa_occupancy_p
-
-    n, saw, block = case
-    rng = np.random.default_rng(int(n + saw))
-    m = jnp.asarray(rng.integers(1, 5000, n).astype(np.float64))
-    k = jnp.asarray(rng.integers(1, 600, n).astype(np.float64))
-    nn = jnp.asarray(rng.integers(1, 5000, n).astype(np.float64))
-    with jax.experimental.enable_x64():
-        got = sa_occupancy_p(m, k, nn, saw, block=block, interpret=True)
-        want = ref.ref_sa_occupancy(m, k, nn, saw)
-        for key in got:
-            np.testing.assert_allclose(np.asarray(got[key]),
-                                       np.asarray(want[key]),
-                                       rtol=1e-12, atol=0)
-        # and against the int64 host batch (the production oracle)
-        b = gating_stats_batch(np.asarray(m, np.int64),
-                               np.asarray(k, np.int64),
-                               np.asarray(nn, np.int64), int(saw))
-        np.testing.assert_allclose(np.asarray(got["frac_on"]),
-                                   b.frac_on, rtol=1e-12, atol=0)
-        np.testing.assert_allclose(np.asarray(got["frac_off"]),
-                                   b.frac_off, rtol=1e-12, atol=0)
-
-
-def test_sa_occupancy_kernel_vmapped_traced_saw():
-    """vmap over the SA width — exactly how the sweep kernel drives the
-    pair axis — plus the weight-load-cycle override and empty streams."""
-    from repro.core.sa_gating import gating_stats_batch
-    from repro.kernels.sa_occupancy import sa_occupancy_p
-
-    rng = np.random.default_rng(5)
-    m = jnp.asarray(rng.integers(1, 2000, 200).astype(np.float64))
-    k = jnp.asarray(rng.integers(1, 400, 200).astype(np.float64))
-    nn = jnp.asarray(rng.integers(1, 2000, 200).astype(np.float64))
-    with jax.experimental.enable_x64():
-        saws = jnp.asarray([8.0, 128.0, 256.0])
-        vm = jax.vmap(lambda s: sa_occupancy_p(m, k, nn, s))(saws)
-        for i, saw in enumerate((8, 128, 256)):
-            b = gating_stats_batch(np.asarray(m, np.int64),
-                                   np.asarray(k, np.int64),
-                                   np.asarray(nn, np.int64), saw)
-            np.testing.assert_allclose(np.asarray(vm["frac_on"][i]),
-                                       b.frac_on, rtol=1e-12, atol=0)
-        # wlc override
-        got = sa_occupancy_p(m, k, nn, 128.0, weight_load_cycles=0.0)
-        b0 = gating_stats_batch(np.asarray(m, np.int64),
-                                np.asarray(k, np.int64),
-                                np.asarray(nn, np.int64), 128,
-                                weight_load_cycles=0)
-        np.testing.assert_allclose(np.asarray(got["frac_w_on"]),
-                                   b0.frac_w_on, rtol=1e-12, atol=0)
-        # empty op stream short-circuits without a pallas_call
-        e = sa_occupancy_p(jnp.zeros(0), jnp.zeros(0), jnp.zeros(0),
-                           128.0)
-        assert all(v.shape == (0,) for v in e.values())

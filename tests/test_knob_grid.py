@@ -14,8 +14,7 @@ unconditionally, and a hand-built record missing one fails loudly.
 import pytest
 
 from repro.core import session
-from repro.core.backend import (default_backend, set_default_backend,
-                                set_sa_occupancy_impl)
+from repro.core.backend import default_backend, set_default_backend
 from repro.core.opgen import paper_suite
 from repro.core.policies import KnobGrid, PolicyKnobs, as_knob_tuple
 from repro.core.sa_gating import gating_cache_info
@@ -174,11 +173,11 @@ def test_session_scopes_and_nests():
     with SweepSession(backend="jax") as outer:
         assert default_backend() == "jax"
         assert session.resolve("jax_mesh") is None
-        with SweepSession(backend="numpy", sa_occupancy_impl="pallas"):
+        with SweepSession(backend="numpy", gating_cache_size=64):
             assert default_backend() == "numpy"
-            assert session.resolve("sa_occupancy_impl") == "pallas"
+            assert session.resolve("gating_cache_size") == 64
         assert default_backend() == "jax"
-        assert session.resolve("sa_occupancy_impl") == "jnp"
+        assert session.resolve("gating_cache_size") is None
         assert outer is not None
     assert default_backend() == "numpy"
 
@@ -206,15 +205,6 @@ def test_legacy_setters_write_the_root_layer():
     assert default_backend() == "numpy"
 
 
-def test_sa_occupancy_setter_delegates():
-    try:
-        prev = set_sa_occupancy_impl("pallas")
-        assert prev == "jnp"
-        assert session.resolve("sa_occupancy_impl") == "pallas"
-    finally:
-        set_sa_occupancy_impl("jnp")
-
-
 def test_gating_cache_size_scoped():
     before = gating_cache_info().maxsize
     with SweepSession(gating_cache_size=128):
@@ -228,8 +218,8 @@ def test_gating_cache_size_scoped():
 def test_session_validation_and_reentrancy():
     with pytest.raises(KeyError, match="unknown array backend"):
         SweepSession(backend="torch")
-    with pytest.raises(KeyError, match="sa_occupancy"):
-        SweepSession(sa_occupancy_impl="xla")
+    with pytest.raises(ValueError, match="GuardPolicy"):
+        SweepSession(guard="strict")
     s = SweepSession(backend="numpy")
     with s:
         with pytest.raises(RuntimeError, match="not re-entrant"):

@@ -157,11 +157,6 @@ def test_perturbed_stack_numpy_matches_scalar_oracle():
 
 def test_perturbed_stack_jax_matches_numpy():
     pytest.importorskip("jax")
-    from repro.core.backend import get_backend
-    bk = get_backend("jax")
-    if bk._x64_ctx is None and not bk.x64_enabled():
-        pytest.skip("this jax has no scoped x64 switch and "
-                    "jax_enable_x64 is off")
     pert = perturb_suite([WL, dlrm_workload("S")], severity_plan(2.0),
                          seed=2)
     grid = (PolicyKnobs(window_scale=1 / 16), PolicyKnobs(),

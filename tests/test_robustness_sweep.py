@@ -113,11 +113,6 @@ def test_threshold_scales_validated():
 
 def test_jax_backend_matches_numpy(out):
     pytest.importorskip("jax")
-    from repro.core.backend import get_backend
-    bk = get_backend("jax")
-    if bk._x64_ctx is None and not bk.x64_enabled():
-        pytest.skip("this jax has no scoped x64 switch and "
-                    "jax_enable_x64 is off")
     oj = sweep_robustness(WLS, severities=SEVS, threshold_scales=TS,
                           seed=0, backend="jax")
     assert len(oj["records"]) == len(out["records"])
