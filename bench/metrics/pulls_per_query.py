@@ -1,0 +1,8 @@
+"""pulls_per_query: arrays pulled back to the host per query, the
+``arrays`` count of the program's ``regate.harvest`` spans. Nothing is
+returned where the program opens no such span."""
+from bench import program_spans
+
+
+def read(red: dict):
+    return program_spans.count_per_query(red, "regate.harvest", "arrays")

@@ -24,6 +24,13 @@ The contract each backend provides:
   program-plane event kernel's spine (``repro.core.program_plane``);
 * ``asarray`` / ``to_numpy`` / ``compute_scope()`` — transfer in/out and
   the dtype discipline scope (jax: float64 via x64);
+* ``span(name, counts=None)`` — a context manager around one layer of
+  the call path (``regate.*``): on jax a
+  ``jax.profiler.TraceAnnotation``, so a running profiler records it on
+  the device trace's clock, with the integers that the callable
+  ``counts`` returns as event stats (called only while a profiler
+  records); it costs about a microsecond when none runs. On numpy a
+  no-op;
 * ``sa_occupancy(...)`` — the in-program SA PE-occupancy pass: the
   backend-neutral closed form, traced on jax, so SA width rides the
   knob axis;
@@ -71,6 +78,21 @@ def _tree_stack(items: list):
     return np.stack(items, axis=0)
 
 
+def transfer_counts(tree) -> dict:
+    """``arrays`` and ``bytes`` of a dict pytree's leaves: the counts a
+    ``regate.put`` or ``regate.harvest`` span carries. Device arrays
+    report ``nbytes`` without a transfer; a Python scalar is read as
+    the 0-d array it is put as (8 bytes under x64)."""
+    if isinstance(tree, dict):
+        n = b = 0
+        for v in tree.values():
+            c = transfer_counts(v)
+            n, b = n + c["arrays"], b + c["bytes"]
+        return {"arrays": n, "bytes": b}
+    return {"arrays": 1, "bytes": int(tree.nbytes if hasattr(tree, "nbytes")
+                                      else np.asarray(tree).nbytes)}
+
+
 class NumpyBackend:
     """Eager numpy instantiation of the backend contract (the oracle)."""
 
@@ -94,6 +116,10 @@ class NumpyBackend:
     @staticmethod
     def to_numpy(x) -> np.ndarray:
         return np.asarray(x)
+
+    @staticmethod
+    def span(name: str, counts=None):
+        return contextlib.nullcontext()
 
     @staticmethod
     def segment_sum(data, seg_ids, num_segments: int):
@@ -174,6 +200,16 @@ class JaxBackend:
 
     def to_numpy(self, x) -> np.ndarray:
         return np.asarray(x)
+
+    def span(self, name: str, counts: Optional[Callable[[], dict]] = None):
+        """A host span for the profiler; the integers ``counts()``
+        returns become its event stats in the ``.xplane.pb``. They are
+        counted only while a profiler records: walking a tree of device
+        arrays costs more than the span itself."""
+        ann = self._jax.profiler.TraceAnnotation
+        if counts is not None and ann.is_enabled():
+            return ann(name, **counts())
+        return ann(name)
 
     def segment_sum(self, data, seg_ids, num_segments: int):
         import jax.ops

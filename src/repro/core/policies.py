@@ -65,7 +65,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.core import backend as backend_mod
-from repro.core.backend import gap_index, get_backend
+from repro.core.backend import gap_index, get_backend, transfer_counts
 from repro.core.hw import NPUSpec, get_npu, with_sa_width
 from repro.core.opgen import (Op, StackedTrace, TraceArrays, Workload,
                               compile_trace, segment_sum, segmented_gaps,
@@ -1720,8 +1720,10 @@ def _backend_data(st: StackedTrace, npu: NPUSpec, bk) \
     hit = st._derived.get(key)
     if hit is not None and hit[0] is npu:
         return hit[1], hit[2]
-    host, sram_setpm = _host_columns(st, npu)
-    data = _put_tree(host, bk)
+    with bk.span("regate.host_columns"):
+        host, sram_setpm = _host_columns(st, npu)
+    with bk.span("regate.put", lambda: transfer_counts(host)):
+        data = _put_tree(host, bk)
     st._derived[key] = (npu, data, sram_setpm)
     return data, sram_setpm
 
@@ -1740,7 +1742,8 @@ def _sharded_backend_data(st: StackedTrace, npu: NPUSpec, bk,
     hit = st._derived.get(key)
     if hit is not None and hit[0] is npu:
         return hit[1], hit[2]
-    host, sram_setpm = _host_columns(st, npu)
+    with bk.span("regate.host_columns"):
+        host, sram_setpm = _host_columns(st, npu)
     op = dict(host["op"])
     n = len(op["seg_ids"])
     pad = (-n) % wl_size
@@ -1753,7 +1756,9 @@ def _sharded_backend_data(st: StackedTrace, npu: NPUSpec, bk,
             else:
                 v = fill.get(k, 0.0)
             op[k] = np.concatenate([a, np.full(pad, v, a.dtype)])
-    data = _put_tree({**host, "op": op}, bk)
+    host = {**host, "op": op}
+    with bk.span("regate.put", lambda: transfer_counts(host)):
+        data = _put_tree(host, bk)
     st._derived[key] = (npu, data, sram_setpm)
     return data, sram_setpm
 
@@ -1824,23 +1829,25 @@ def _knob_arrays(knob_grid, npu: NPUSpec, bk, pad_to: int = 0) -> dict:
         pair_saw_idx, pair_ds, pair_ws, saw_unique = (
             padded(a, pad_to)
             for a in (pair_saw_idx, pair_ds, pair_ws, saw_unique))
-    return {
-        "dscale": bk.asarray(ds),
-        "wscale": bk.asarray(ws),
-        "leak_logic": bk.asarray(leak_logic),
-        "leak_sleep": bk.asarray(leak_sleep),
-        "leak_off": bk.asarray(leak_off),
+    host = {
+        "dscale": ds,
+        "wscale": ws,
+        "leak_logic": leak_logic,
+        "leak_sleep": leak_sleep,
+        "leak_off": leak_off,
         # the width-dependent base pass runs once per distinct width
         # (replicated under shard_map); the heavy masked merges once per
         # distinct (width, delay) pair; the inverse indices map both
         # back onto the full grid
-        "saw_unique": bk.asarray(saw_unique),
-        "saw_inv": bk.asarray(saw_inv),
-        "pair_saw_idx": bk.asarray(pair_saw_idx),
-        "pair_dscale": bk.asarray(pair_ds),
-        "pair_wscale": bk.asarray(pair_ws),
-        "pair_inv": bk.asarray(inv),
+        "saw_unique": saw_unique,
+        "saw_inv": saw_inv,
+        "pair_saw_idx": pair_saw_idx,
+        "pair_dscale": pair_ds,
+        "pair_wscale": pair_ws,
+        "pair_inv": inv,
     }
+    with bk.span("regate.put", lambda: transfer_counts(host)):
+        return _put_tree(host, bk)
 
 
 def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
@@ -1891,7 +1898,8 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                                      pad_to=knob_size)
                 kern = _shard_kernel(bk, mesh, policies, wl_axis,
                                      knob_axis)
-                vm = bk.block(kern(data, knobs))
+                with bk.span("regate.sweep_kernel"):
+                    vm = bk.block(kern(data, knobs))
             else:
                 if mesh is None:
                     data, sram_setpm = _backend_data(st, npu, bk)
@@ -1903,45 +1911,48 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                     data = bk.shard_data(data, mesh)
                 knobs = _knob_arrays(knob_grid, npu, bk)
                 kern = _backend_kernel(bk)
-                vm = bk.block(kern(data, knobs, policies))
+                with bk.span("regate.sweep_kernel"):
+                    vm = bk.block(kern(data, knobs, policies))
 
             def harvest(arr):
                 # (K_pad, W) -> (W, K); drop any shard padding
                 return bk.to_numpy(arr)[:k_n].T
 
-            cells = {cid: {q: harvest(arr) for q, arr in d.items()}
-                     for cid, d in vm["cells"].items()}
-            sram_static = {s: harvest(arr)
-                           for s, arr in vm["sram"].items()}
-            d_seg = harvest(vm["D_seg"])
-            dyn = {c: harvest(vm["dyn"][c]) for c in _BK_COMPS}
-            sram_gu = harvest(vm["sram_GU"])
-            sram_dyn = harvest(vm["sram_dyn"])
-            pm = PowerModel(npu)
-            for pi, policy in enumerate(policies):
-                cp = _component_policies(policy)
-                ov_total = np.zeros((w, k_n))
-                for c in _BK_COMPS:
-                    cl = cells[_cell_id(c, cp[c])]
-                    static_j[c][:, ai, pi, :] = cl["static"]
-                    wake_events[c][:, ai, pi, :] = cl["wakes"]
-                    setpm_by[c][:, ai, pi, :] = cl["setpm"]
-                    gated_s[c][:, ai, pi, :] = cl["gated"]
-                    dynamic_j[c][:, ai, pi, :] = dyn[c]
-                    ov_total += cl["overhead"]
-                pol = cp["sram"]
-                static_j["sram"][:, ai, pi, :] = \
-                    sram_static[pol.sram_state]
-                if pol.sram_state != "on":
-                    gated_s["sram"][:, ai, pi, :] = sram_gu
-                if pol.sram_state in ("sleep", "off") and pol.mode == "sw":
-                    setpm_by["sram"][:, ai, pi, :] = sram_setpm[:, None]
-                dynamic_j["sram"][:, ai, pi, :] = sram_dyn
-                static_j["other"][:, ai, pi, :] = \
-                    pm.static_w["other"] * d_seg
-                dynamic_j["other"][:, ai, pi, :] = \
-                    pm.dyn_max_w["other"] * 0.3 * d_seg
-                runtime[:, ai, pi, :] = d_seg + ov_total
+            with bk.span("regate.harvest", lambda: transfer_counts(vm)):
+                cells = {cid: {q: harvest(arr) for q, arr in d.items()}
+                         for cid, d in vm["cells"].items()}
+                sram_static = {s: harvest(arr)
+                               for s, arr in vm["sram"].items()}
+                d_seg = harvest(vm["D_seg"])
+                dyn = {c: harvest(vm["dyn"][c]) for c in _BK_COMPS}
+                sram_gu = harvest(vm["sram_GU"])
+                sram_dyn = harvest(vm["sram_dyn"])
+            with bk.span("regate.assemble"):
+                pm = PowerModel(npu)
+                for pi, policy in enumerate(policies):
+                    cp = _component_policies(policy)
+                    ov_total = np.zeros((w, k_n))
+                    for c in _BK_COMPS:
+                        cl = cells[_cell_id(c, cp[c])]
+                        static_j[c][:, ai, pi, :] = cl["static"]
+                        wake_events[c][:, ai, pi, :] = cl["wakes"]
+                        setpm_by[c][:, ai, pi, :] = cl["setpm"]
+                        gated_s[c][:, ai, pi, :] = cl["gated"]
+                        dynamic_j[c][:, ai, pi, :] = dyn[c]
+                        ov_total += cl["overhead"]
+                    pol = cp["sram"]
+                    static_j["sram"][:, ai, pi, :] = \
+                        sram_static[pol.sram_state]
+                    if pol.sram_state != "on":
+                        gated_s["sram"][:, ai, pi, :] = sram_gu
+                    if pol.sram_state in ("sleep", "off") and pol.mode == "sw":
+                        setpm_by["sram"][:, ai, pi, :] = sram_setpm[:, None]
+                    dynamic_j["sram"][:, ai, pi, :] = sram_dyn
+                    static_j["other"][:, ai, pi, :] = \
+                        pm.static_w["other"] * d_seg
+                    dynamic_j["other"][:, ai, pi, :] = \
+                        pm.dyn_max_w["other"] * 0.3 * d_seg
+                    runtime[:, ai, pi, :] = d_seg + ov_total
     return result
 
 
@@ -2016,9 +2027,10 @@ def evaluate_batch(workloads, npus=("NPU-D",), policies=POLICIES,
     if backend != "numpy" or jax_mesh is not None:
         if jax_mesh is not None and backend == "numpy":
             raise ValueError("jax_mesh requires backend='jax'")
-        return _evaluate_batch_backend(workloads, npu_specs, policies,
-                                       knob_grid, get_backend(backend),
-                                       mesh=jax_mesh)
+        bk = get_backend(backend)
+        with bk.span("regate.evaluate_batch"):
+            return _evaluate_batch_backend(workloads, npu_specs, policies,
+                                           knob_grid, bk, mesh=jax_mesh)
     st = stack_traces(workloads)
     W, A, P, K = len(workloads), len(npu_specs), len(policies), \
         len(knob_grid)

@@ -50,7 +50,7 @@ from typing import Iterable, Optional, Sequence
 import numpy as np
 
 from repro.core import session
-from repro.core.backend import get_backend
+from repro.core.backend import get_backend, transfer_counts
 from repro.core.hw import NPUSpec, get_npu, with_sa_width
 from repro.core.isa import events_to_arrays, scaled_delay, scaled_window
 from repro.core.lowering import (COMP_OF_UNIT, REGATE_FULL_TIMELINE,
@@ -285,13 +285,25 @@ def _compiled(bk):
     return fn
 
 
+def _scan_counts(data: dict) -> dict:
+    """The ``regate.scan_kernel`` span's stats: rows scanned, the
+    padded event depth and the real events summed over rows."""
+    cycle = data["cycle"]
+    return {"rows": cycle.shape[1], "e_max": cycle.shape[0],
+            "events": int(np.count_nonzero(cycle >= 0))}
+
+
 def _run_kernel(data: dict, bk) -> dict[str, np.ndarray]:
     """Execute the packed event stack on the backend; returns host
     numpy outputs per row."""
     fn = _compiled(bk)
     with bk.compute_scope():
-        out = bk.block(fn({k: bk.asarray(v) for k, v in data.items()}))
-    return {k: bk.to_numpy(v) for k, v in out.items()}
+        with bk.span("regate.put", lambda: transfer_counts(data)):
+            dev = {k: bk.asarray(v) for k, v in data.items()}
+        with bk.span("regate.scan_kernel", lambda: _scan_counts(data)):
+            out = bk.block(fn(dev))
+    with bk.span("regate.harvest", lambda: transfer_counts(out)):
+        return {k: bk.to_numpy(v) for k, v in out.items()}
 
 
 _KERNELS: dict[str, object] = {}
@@ -459,67 +471,71 @@ def program_plane_batch(workloads: Sequence[Workload] | Workload,
 
     triples, inv = knob_pairs(grid)
     w_n, a_n, t_n = len(workloads), len(npu_specs), len(triples)
-    pa, stream_of_row, data = _exec_rows(workloads, npu_specs, triples)
-    if jax_mesh is not None and bk.name == "jax" \
-            and "wl" in bk.mesh_axis_sizes(jax_mesh):
-        out = _run_kernel_mesh(data, bk, jax_mesh)
-    else:
-        out = _run_kernel(data, bk)
+    with bk.span("regate.program_plane_batch"):
+        with bk.span("regate.exec_rows"):
+            pa, stream_of_row, data = _exec_rows(workloads, npu_specs,
+                                                 triples)
+        if jax_mesh is not None and bk.name == "jax" \
+                and "wl" in bk.mesh_axis_sizes(jax_mesh):
+            out = _run_kernel_mesh(data, bk, jax_mesh)
+        else:
+            out = _run_kernel(data, bk)
 
-    shape = (w_n, a_n, t_n)
-    cycles = out["cycles"].reshape(shape)
-    stalls = out["stall_cycles"].reshape(shape)
-    gated_u = out["gated"].reshape(shape + (len(UNITS),))
-    wakes_u = out["wakes"].reshape(shape + (len(UNITS),))
-    n_events = pa.lengths[stream_of_row].reshape(shape)
+        shape = (w_n, a_n, t_n)
+        cycles = out["cycles"].reshape(shape)
+        stalls = out["stall_cycles"].reshape(shape)
+        gated_u = out["gated"].reshape(shape + (len(UNITS),))
+        wakes_u = out["wakes"].reshape(shape + (len(UNITS),))
+        n_events = pa.lengths[stream_of_row].reshape(shape)
 
-    gated = {c: gated_u[..., ui].astype(np.float64)
-             for ui, c in enumerate(COMPS)}
-    wakes = {c: wakes_u[..., ui].astype(np.float64)
-             for ui, c in enumerate(COMPS)}
-    setpm_isa = {"vu": pa.setpm_vu[stream_of_row].reshape(shape).copy(),
-                 "sram": np.zeros(shape)}
-    gated["sram"] = np.zeros(shape)
+        gated = {c: gated_u[..., ui].astype(np.float64)
+                 for ui, c in enumerate(COMPS)}
+        wakes = {c: wakes_u[..., ui].astype(np.float64)
+                 for ui, c in enumerate(COMPS)}
+        setpm_isa = {"vu": pa.setpm_vu[stream_of_row].reshape(shape).copy(),
+                     "sram": np.zeros(shape)}
+        gated["sram"] = np.zeros(shape)
 
-    # closed-form folds, once per unique (workload, npu, triple) —
-    # identical host calls to execute_program's, so bit-identical; the
-    # SRAM band analysis is window-independent, so it further dedups to
-    # one call per (program, delay_scale)
-    pol_vu = _component_policies("ReGate-Full")["vu"]
-    sram_memo: dict[tuple[int, float], dict] = {}
-    for wi, wl in enumerate(workloads):
-        for ai, npu in enumerate(npu_specs):
-            for ti, (saw, dsc, wsc) in enumerate(triples):
-                npu_eff = with_sa_width(npu, saw)
-                prog = lower_workload(wl, npu_eff)
-                kn = PolicyKnobs(delay_scale=dsc, window_scale=wsc,
-                                 sa_width=saw)
-                fv = _fine_grained_vu_vec(
-                    prog.tm, prog.tr, npu_eff, pol_vu, 1.0,
-                    npu_eff.gating.leak_off_logic, kn)
-                gated["vu"][wi, ai, ti] = (
-                    gated["vu"][wi, ai, ti]
-                    + fv["gated_s"] * npu_eff.freq_hz)
-                setpm_isa["vu"][wi, ai, ti] += fv["setpm"]
-                wakes["vu"][wi, ai, ti] += fv["wakes"]
-                skey = (id(prog), float(dsc))
-                sb = sram_memo.get(skey)
-                if sb is None:
-                    sb = sram_band_gating(prog, delay_scale=dsc)
-                    sram_memo[skey] = sb
-                gated["sram"][wi, ai, ti] = (
-                    sb["gated_segcycles"] / max(1, sb["n_segments"]))
-                setpm_isa["sram"][wi, ai, ti] = sb["setpm"]
+        # closed-form folds, once per unique (workload, npu, triple) —
+        # identical host calls to execute_program's, so bit-identical; the
+        # SRAM band analysis is window-independent, so it further dedups to
+        # one call per (program, delay_scale)
+        with bk.span("regate.folds"):
+            pol_vu = _component_policies("ReGate-Full")["vu"]
+            sram_memo: dict[tuple[int, float], dict] = {}
+            for wi, wl in enumerate(workloads):
+                for ai, npu in enumerate(npu_specs):
+                    for ti, (saw, dsc, wsc) in enumerate(triples):
+                        npu_eff = with_sa_width(npu, saw)
+                        prog = lower_workload(wl, npu_eff)
+                        kn = PolicyKnobs(delay_scale=dsc, window_scale=wsc,
+                                         sa_width=saw)
+                        fv = _fine_grained_vu_vec(
+                            prog.tm, prog.tr, npu_eff, pol_vu, 1.0,
+                            npu_eff.gating.leak_off_logic, kn)
+                        gated["vu"][wi, ai, ti] = (
+                            gated["vu"][wi, ai, ti]
+                            + fv["gated_s"] * npu_eff.freq_hz)
+                        setpm_isa["vu"][wi, ai, ti] += fv["setpm"]
+                        wakes["vu"][wi, ai, ti] += fv["wakes"]
+                        skey = (id(prog), float(dsc))
+                        sb = sram_memo.get(skey)
+                        if sb is None:
+                            sb = sram_band_gating(prog, delay_scale=dsc)
+                            sram_memo[skey] = sb
+                        gated["sram"][wi, ai, ti] = (
+                            sb["gated_segcycles"] / max(1, sb["n_segments"]))
+                        setpm_isa["sram"][wi, ai, ti] = sb["setpm"]
 
-    # the policy columns ride the same backend; the mesh is applied to
-    # the kernel only (its row axis pads to divide the mesh — the
-    # closed-form engine's op axis has no such padding and resolves its
-    # own session mesh like every other sweep entry point)
-    policy = evaluate_batch(workloads, npu_specs, ("ReGate-Full",),
-                            grid, backend=backend)
-    return ProgramPlaneBatch(
-        workloads=tuple(wl.name for wl in workloads),
-        npus=tuple(npu_specs), knob_grid=grid, triples=triples,
-        inv=inv, cycles=cycles, stall_cycles=stalls, n_events=n_events,
-        gated_cycles=gated, wake_events=wakes, setpm_isa=setpm_isa,
-        policy=policy)
+        # the policy columns ride the same backend; the mesh is applied to
+        # the kernel only (its row axis pads to divide the mesh — the
+        # closed-form engine's op axis has no such padding and resolves its
+        # own session mesh like every other sweep entry point)
+        policy = evaluate_batch(workloads, npu_specs, ("ReGate-Full",),
+                                grid, backend=backend)
+        return ProgramPlaneBatch(
+            workloads=tuple(wl.name for wl in workloads),
+            npus=tuple(npu_specs), knob_grid=grid, triples=triples,
+            inv=inv, cycles=cycles, stall_cycles=stalls, n_events=n_events,
+            gated_cycles=gated, wake_events=wakes, setpm_isa=setpm_isa,
+            policy=policy)
