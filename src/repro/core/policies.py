@@ -1265,7 +1265,8 @@ def _sweep_kernel(data, knobs, policies, bk, wl_axis=None, knob_axis=None):
     Returns a dict of (K, W) arrays: per-cell quantities (``cells``),
     SRAM static per state (``sram``), and the per-knob context
     (``D_seg``, ``dyn``, ``sram_GU``, ``sram_dyn``) the host assembly
-    broadcasts from.
+    broadcasts from. ``_backend_kernel`` and ``_shard_kernel`` return
+    them ``_pack``-ed into one array, so the host pulls it once.
     """
     xp = bk.xp
     op = data["op"]
@@ -1563,6 +1564,51 @@ def _sweep_kernel(data, knobs, policies, bk, wl_axis=None, knob_axis=None):
             "sram_GU": base["sram_GU"], "sram_dyn": base["sram_dyn"]}
 
 
+_CELL_QS = ("static", "wakes", "setpm", "gated", "overhead")
+
+# the order in which the sweep program packs its outputs, per policies
+# tuple: fixed at trace time, rebuilt by the host from the same policies
+_LAYOUTS: dict[tuple, tuple] = {}
+
+
+def _out_layout(policies) -> tuple[tuple[str, ...], ...]:
+    """Key paths of ``_sweep_kernel``'s output leaves, in the order
+    ``_pack`` stacks them and ``_unpack`` reads them back."""
+    hit = _LAYOUTS.get(policies)
+    if hit is None:
+        hit = (tuple(("cells", cid, q) for cid in _distinct_cells(policies)
+                     for q in _CELL_QS)
+               + tuple(("sram", s) for s in _sram_states(policies))
+               + (("D_seg",),) + tuple(("dyn", c) for c in _BK_COMPS)
+               + (("sram_GU",), ("sram_dyn",)))
+        _LAYOUTS[policies] = hit
+    return hit
+
+
+def _pack(out: dict, policies, xp):
+    """Every (K, W) output leaf stacked into one (n_out, K, W) slab in
+    ``_out_layout`` order, so one transfer harvests an NPU. Stacking
+    copies float64 exactly."""
+    def leaf(path):
+        v = out
+        for key in path:
+            v = v[key]
+        return v
+    return xp.stack([leaf(path) for path in _out_layout(policies)])
+
+
+def _unpack(slab: np.ndarray, policies) -> dict:
+    """``_pack`` undone on the host: the kernel's output dict, each leaf
+    a (W, K) view of the (n_out, K, W) ``slab``."""
+    out: dict = {}
+    for path, arr in zip(_out_layout(policies), slab):
+        d = out
+        for key in path[:-1]:
+            d = d.setdefault(key, {})
+        d[path[-1]] = arr.T
+    return out
+
+
 # jitted sweep kernels cached per backend: the jax program compiles
 # once per (stack shape, knob count, policies) and is reused across NPU
 # generations and repeated sweeps
@@ -1571,11 +1617,12 @@ _KERNELS: dict[str, object] = {}
 
 def _backend_kernel(bk):
     """The (possibly jitted) single-device sweep kernel for one
-    backend."""
+    backend, its outputs packed by ``_pack``."""
     fn = _KERNELS.get(bk.name)
     if fn is None:
         def kern(data, knobs, policies):
-            return _sweep_kernel(data, knobs, policies, bk)
+            return _pack(_sweep_kernel(data, knobs, policies, bk),
+                         policies, bk.xp)
         fn = bk.jit(kern, static_argnames=("policies",))
         _KERNELS[bk.name] = fn
     return fn
@@ -1605,12 +1652,14 @@ def _shard_kernel(bk, mesh, policies, wl_axis, knob_axis):
     knob_spec = pspec(knob_axis)
 
     def body(data, knobs):
-        return _sweep_kernel(data, knobs, policies, bk,
-                             wl_axis=wl_axis, knob_axis=knob_axis)
+        return _pack(_sweep_kernel(data, knobs, policies, bk,
+                                   wl_axis=wl_axis, knob_axis=knob_axis),
+                     policies, bk.xp)
 
+    # the packed slab's knob axis (axis 1) is the sharded one
     fn = bk.shard_map_kernel(body, mesh,
                              in_specs=(data_spec, knob_spec),
-                             out_specs=pspec(knob_axis))
+                             out_specs=pspec(None, knob_axis))
     _SHARD_KERNELS[key] = (mesh, fn)
     return fn
 
@@ -1899,7 +1948,7 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                 kern = _shard_kernel(bk, mesh, policies, wl_axis,
                                      knob_axis)
                 with bk.span("regate.sweep_kernel"):
-                    vm = bk.block(kern(data, knobs))
+                    slab = bk.block(kern(data, knobs))
             else:
                 if mesh is None:
                     data, sram_setpm = _backend_data(st, npu, bk)
@@ -1912,21 +1961,14 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                 knobs = _knob_arrays(knob_grid, npu, bk)
                 kern = _backend_kernel(bk)
                 with bk.span("regate.sweep_kernel"):
-                    vm = bk.block(kern(data, knobs, policies))
+                    slab = bk.block(kern(data, knobs, policies))
 
-            def harvest(arr):
-                # (K_pad, W) -> (W, K); drop any shard padding
-                return bk.to_numpy(arr)[:k_n].T
-
-            with bk.span("regate.harvest", lambda: transfer_counts(vm)):
-                cells = {cid: {q: harvest(arr) for q, arr in d.items()}
-                         for cid, d in vm["cells"].items()}
-                sram_static = {s: harvest(arr)
-                               for s, arr in vm["sram"].items()}
-                d_seg = harvest(vm["D_seg"])
-                dyn = {c: harvest(vm["dyn"][c]) for c in _BK_COMPS}
-                sram_gu = harvest(vm["sram_GU"])
-                sram_dyn = harvest(vm["sram_dyn"])
+            # one pull per NPU: (n_out, K_pad, W), shard padding dropped
+            with bk.span("regate.harvest", lambda: transfer_counts(slab)):
+                vm = _unpack(bk.to_numpy(slab)[:, :k_n], policies)
+            cells, sram_static, dyn = vm["cells"], vm["sram"], vm["dyn"]
+            d_seg, sram_gu, sram_dyn = \
+                vm["D_seg"], vm["sram_GU"], vm["sram_dyn"]
             with bk.span("regate.assemble"):
                 pm = PowerModel(npu)
                 for pi, policy in enumerate(policies):
