@@ -565,12 +565,129 @@ def diffusion_workload(model: str, *, batch: int = 8, n_chips: int = 4) \
 # Assigned-architecture workloads (execution plane -> power plane bridge)
 # --------------------------------------------------------------------------
 
+def _attention_ops(cfg: ArchConfig, T: int, kv_len: int, decode: bool,
+                   tp: int) -> list[Op]:
+    """Grouped-query attention: fused qkv projection, the attention
+    itself (prefill on the SA, decode on the VU against the KV cache),
+    and the output projection; heads split ``tp`` ways."""
+    D = cfg.d_model
+    H = max(1, cfg.n_heads)
+    hd = max(1, cfg.head_dim)
+    ops = [_matmul("qkv", T, D, (H + 2 * cfg.n_kv_heads) * hd // tp)]
+    if decode:
+        ops.append(Op(
+            "attn_decode",
+            flops_vu=2.0 * T * kv_len * hd * 2 * H / tp,
+            bytes_hbm=kv_len * cfg.n_kv_heads * hd * BF16 * 2
+            * max(1, T // 8) / tp,
+            sram_demand=8 << 20))
+    else:
+        ops.append(Op(
+            "attention", flops_sa=2.0 * T * kv_len * hd * 2 * H / tp,
+            bytes_hbm=3 * T * D * BF16 / tp,
+            matmul_dims=(T, hd, kv_len), sram_demand=24 << 20))
+    ops.append(_matmul("out_proj", T, H * hd // tp, D))
+    return ops
+
+
+def _mla_ops(cfg: ArchConfig, T: int, kv_len: int, decode: bool,
+             tp: int) -> list[Op]:
+    """DeepSeek-V2 multi-head latent attention (arXiv:2405.04434 §2.1)
+    for ``T`` tokens over this chip's ``n_heads / tp`` heads.
+
+    Both low-rank down projections run whole on every chip. Prefill and
+    train expand the latent into per-head keys and values (``kv_b``)
+    and attend as ordinary heads. Decode absorbs ``kv_b`` into the query
+    and the output, as the paper serves it: every head then reads the
+    one shared latent cache of ``kv_lora + rope`` per position, one
+    (H, kv_lora + rope) x (kv_lora + rope, kv_len) pass per sequence on
+    the SA. The absorbed projections stream one (nope, kv_lora) or
+    (kv_lora, v) block of ``kv_b`` per head."""
+    m, D, H = cfg.mla, cfg.d_model, max(1, cfg.n_heads // tp)
+    qk = m.nope_head_dim + m.rope_head_dim
+    lat = m.kv_lora_rank + m.rope_head_dim
+    ops = [_matmul("q_a", T, D, m.q_lora_rank),
+           _matmul("kv_a", T, D, lat),
+           _vector("mla_norm_rope",
+                   T * (m.q_lora_rank + m.kv_lora_rank
+                        + (H + 1) * m.rope_head_dim), flops_per_elem=4),
+           _matmul("q_b", T, m.q_lora_rank, H * qk)]
+    if decode:
+        ops += [
+            _matmul("q_absorb", T * H, m.nope_head_dim, m.kv_lora_rank,
+                    bytes_w=BF16 * H),
+            Op("mla_decode",
+               flops_sa=2.0 * H * kv_len * lat
+               + 2.0 * H * kv_len * m.kv_lora_rank,
+               bytes_hbm=kv_len * lat * BF16,
+               matmul_dims=(H, lat, kv_len), sram_demand=8 << 20, count=T),
+            _matmul("o_absorb", T * H, m.kv_lora_rank, m.v_head_dim,
+                    bytes_w=BF16 * H)]
+    else:
+        ops += [
+            _matmul("kv_b", T, m.kv_lora_rank,
+                    H * (m.nope_head_dim + m.v_head_dim)),
+            Op("attention",
+               flops_sa=2.0 * T * kv_len * H * (qk + m.v_head_dim),
+               bytes_hbm=T * H * (2 * qk + m.v_head_dim) * BF16,
+               matmul_dims=(T, qk, kv_len), sram_demand=24 << 20)]
+    ops.append(_matmul("o_proj", T, H * m.v_head_dim, D))
+    return ops
+
+
+def _routed_experts(R: int, E: int, D: int, F: int) -> list[Op]:
+    """The ``E`` routed experts a chip holds, fed ``R`` token slots
+    spread as evenly as integers allow over ``min(E, R)`` of them. Each
+    expert's up GEMM, SwiGLU and down GEMM is priced at its own M; the
+    experts that share an M are one op's ``count``, so there are at most
+    two ops per GEMM, and every active expert's weights stream once."""
+    n = min(E, R)
+    m, extra = divmod(R, n)
+    ops: list[Op] = []
+    for rows, k in ((m + 1, extra), (m, n - extra)):
+        if k:
+            ops += [_matmul("expert_up", rows, D, 2 * F, count=k),
+                    _vector("expert_swiglu", rows * F, flops_per_elem=3,
+                            bytes_per_elem=0.5, count=k),
+                    _matmul("expert_down", rows, F, D, count=k)]
+    return ops
+
+
+def _deepseek_moe_ops(mo, T: int, D: int, *, n_chips: int,
+                      tp: int) -> list[Op]:
+    """DeepSeekMoE (arXiv:2405.04434 §2.2), expert parallel: the routed
+    experts are spread over ``ep = gcd(n_experts, n_chips)`` chips, and
+    this one holds ``n_experts / ep`` of them. Its ``T // tp`` tokens
+    are routed over all experts, sent once per chosen expert to the
+    expert's chip and combined back (all-to-all), with balanced routing
+    and no token dropped. The shared experts run every token, split
+    ``tp`` ways."""
+    ep = math.gcd(mo.n_experts, n_chips)
+    T_m = max(1, T // tp)
+    R = T_m * mo.top_k
+    a2a = R * D * BF16 * (ep - 1) / ep
+    ops = [_matmul("router", T_m, D, mo.n_experts),
+           _vector("router_topk", T_m * mo.n_experts, flops_per_elem=4),
+           _collective("a2a_dispatch", a2a)]
+    ops += _routed_experts(R, mo.n_experts // ep, D, mo.d_ff_expert)
+    ops.append(_collective("a2a_combine", a2a))
+    if mo.n_shared_experts:
+        fs = mo.n_shared_experts * mo.d_ff_expert
+        ops += [_matmul("shared_up", T, D, 2 * fs // tp),
+                _vector("shared_swiglu", T * fs / tp, flops_per_elem=3,
+                        bytes_per_elem=0.5),
+                _matmul("shared_down", T, fs // tp, D)]
+    return ops
+
+
 def arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int = 256,
                   tp: int = 16) -> Workload:
     """Analytic operator trace for one of our (arch x shape) cells.
 
     Used when HLO statistics are not available (and cross-checked against
-    the dry-run numbers in the benchmarks).
+    the dry-run numbers in the benchmarks). Attention is latent (MLA)
+    where ``cfg.mla`` is set; with ``cfg.moe`` every layer after the
+    leading dense ones runs expert-parallel DeepSeekMoE.
     """
     ops: list[Op] = []
     decode = shape.kind == "decode"
@@ -607,37 +724,25 @@ def arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int = 256,
         ]
         add_layer(layer, cfg.n_layers)
     else:
-        H = max(1, cfg.n_heads)
-        hd = max(1, cfg.head_dim)
-        layer = [
-            _matmul("qkv", T, D, (H + 2 * cfg.n_kv_heads) * hd // tp)]
-        if decode:
-            layer.append(Op(
-                "attn_decode",
-                flops_vu=2.0 * T * kv_len * hd * 2 * H / tp,
-                bytes_hbm=kv_len * cfg.n_kv_heads * hd * BF16 * 2
-                * max(1, T // 8) / tp,
-                sram_demand=8 << 20))
-        else:
-            layer.append(Op(
-                "attention", flops_sa=2.0 * T * kv_len * hd * 2 * H / tp,
-                bytes_hbm=3 * T * D * BF16 / tp,
-                matmul_dims=(T, hd, kv_len), sram_demand=24 << 20))
-        layer.append(_matmul("out_proj", T, H * hd // tp, D))
-        if cfg.moe:
-            mo = cfg.moe
-            layer.append(_collective(
-                "moe_a2a", 2 * T * D * BF16 * (tp - 1) / tp, sram_tile=8 << 20))
-            layer.append(_matmul("experts", T * mo.top_k, D,
-                                 3 * mo.d_ff_expert))
-        elif cfg.d_ff:
-            layer.append(_matmul("mlp_up", T, D, 2 * cfg.d_ff // tp))
-            layer.append(_matmul("mlp_down", T, cfg.d_ff // tp, D))
+        attend = _mla_ops if cfg.mla else _attention_ops
+        attn = attend(cfg, T, kv_len, decode, tp)
+        mlp = []
+        if cfg.d_ff:
+            mlp = [_matmul("mlp_up", T, D, 2 * cfg.d_ff // tp),
+                   _matmul("mlp_down", T, cfg.d_ff // tp, D)]
+        tail = []
         if tp > 1:
-            layer.append(_collective("ar_layer",
-                                     2 * T * D * BF16 * (tp - 1) / tp))
-        layer.append(_vector("norms", T * D, flops_per_elem=8))
-        add_layer(layer, cfg.n_layers)
+            tail.append(_collective("ar_layer",
+                                    2 * T * D * BF16 * (tp - 1) / tp))
+        tail.append(_vector("norms", T * D, flops_per_elem=8))
+        if cfg.moe:
+            # leading dense layers keep the dense MLP at d_ff
+            lead = cfg.moe.first_dense_layers
+            add_layer(attn + mlp + tail, lead)
+            moe = _deepseek_moe_ops(cfg.moe, T, D, n_chips=n_chips, tp=tp)
+            add_layer(attn + moe + tail, cfg.n_layers - lead)
+        else:
+            add_layer(attn + mlp + tail, cfg.n_layers)
 
     ops.append(_matmul("lm_head", T if not train else T,
                        D, cfg.vocab_padded // tp))

@@ -1963,8 +1963,13 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                 with bk.span("regate.sweep_kernel"):
                     slab = bk.block(kern(data, knobs, policies))
 
-            # one pull per NPU: (n_out, K_pad, W), shard padding dropped
-            with bk.span("regate.harvest", lambda: transfer_counts(slab)):
+            # one pull per NPU: (n_out, K_pad, W), shard padding dropped.
+            # Beside the transfer, the span counts the work of the call
+            # it harvests: the stack's op rows (before any mesh padding),
+            # those with matmul dims, and the knob points.
+            with bk.span("regate.harvest", lambda: dict(
+                    transfer_counts(slab), ops=st.n_ops,
+                    mm_ops=int(st.has_mm.sum()), knobs=k_n)):
                 vm = _unpack(bk.to_numpy(slab)[:, :k_n], policies)
             cells, sram_static, dyn = vm["cells"], vm["sram"], vm["dyn"]
             d_seg, sram_gu, sram_dyn = \
