@@ -38,6 +38,12 @@ The contract each backend provides:
   collective surface the multi-device ``shard_map`` sweep program is
   built from (jax only; resolved through ``parallel.jax_compat``).
 
+A put to the chip costs about the same whatever its size, so the
+single-device kernels take their inputs as one flat array per dtype:
+``put_slabs`` packs a host dict pytree and puts each slab once, and
+``unpack_slabs`` rebuilds the dict inside the jitted program by static
+slices, its ``slab_layout`` a static argument.
+
 Ragged gap merging (``opgen.segmented_gaps``) is data-dependent-shape
 and cannot run under ``jit``; ``gap_index`` builds the equivalent
 fixed-shape structure on the host once per stack — each op is assigned
@@ -54,6 +60,7 @@ still come out narrower.
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Callable, Optional
 
 import numpy as np
@@ -91,6 +98,78 @@ def transfer_counts(tree) -> dict:
         return {"arrays": n, "bytes": b}
     return {"arrays": 1, "bytes": int(tree.nbytes if hasattr(tree, "nbytes")
                                       else np.asarray(tree).nbytes)}
+
+
+def slab_layout(tree) -> tuple:
+    """Static layout of a host dict pytree packed one flat array (slab)
+    per dtype: ``(slabs, leaves)``, ``slabs`` the ``(dtype, size)`` of
+    each slab and ``leaves`` the ``(key path, dtype, shape, slab,
+    offset)`` of each leaf, keys walked in sorted order. A leaf rides
+    the slab of its own dtype, a bool leaf the int64 one as 0/1; a
+    Python scalar is the 0-d array it converts to. The layout depends
+    only on keys, dtypes and shapes, so it is hashable and serves as a
+    static argument of a jitted program: new values, the same program."""
+    sizes: dict[str, int] = {}
+    leaves = []
+
+    def walk(t, path):
+        if isinstance(t, dict):
+            for k in sorted(t):
+                walk(t[k], path + (k,))
+            return
+        a = np.asarray(t)
+        slab = "int64" if a.dtype == np.bool_ else a.dtype.name
+        off = sizes.get(slab, 0)
+        leaves.append((path, a.dtype.name, a.shape, slab, off))
+        sizes[slab] = off + a.size
+
+    walk(tree, ())
+    return tuple(sizes.items()), tuple(leaves)
+
+
+def slab_counts(layout) -> dict:
+    """``transfer_counts`` of the slabs ``layout`` packs into."""
+    return {"arrays": len(layout[0]),
+            "bytes": sum(n * np.dtype(d).itemsize for d, n in layout[0])}
+
+
+def pack_slabs(tree, layout) -> dict[str, np.ndarray]:
+    """The leaves of ``tree`` copied into one flat array per dtype, at
+    ``layout``'s offsets: ``{dtype: slab}``."""
+    parts: dict[str, list] = {d: [] for d, _n in layout[0]}
+    for path, _dtype, _shape, slab, _off in layout[1]:
+        v = tree
+        for k in path:
+            v = v[k]
+        parts[slab].append(np.ravel(np.asarray(v, slab)))
+    return {d: np.concatenate(p) for d, p in parts.items()}
+
+
+def unpack_slabs(slabs: dict, layout) -> dict:
+    """``pack_slabs`` undone by static slices and reshapes (inside a
+    jitted program, on traced slabs): the same dict pytree, every leaf
+    with its dtype, shape and bits."""
+    out: dict = {}
+    for path, dtype, shape, slab, off in layout[1]:
+        v = slabs[slab][off:off + math.prod(shape)].reshape(shape)
+        if dtype == "bool":
+            v = v != 0
+        d = out
+        for k in path[:-1]:
+            d = d.setdefault(k, {})
+        d[path[-1]] = v
+    return out
+
+
+def put_slabs(tree, bk) -> tuple[tuple, dict]:
+    """One ``regate.put`` of a host dict pytree: packed into one slab
+    per dtype on the host, each slab put once. Returns ``(layout,
+    {dtype: device slab})``; ``unpack_slabs`` rebuilds the tree. The
+    packing copy counts as transfer work, inside the span."""
+    layout = slab_layout(tree)
+    with bk.span("regate.put", lambda: slab_counts(layout)):
+        return layout, {d: bk.asarray(s)
+                        for d, s in pack_slabs(tree, layout).items()}
 
 
 class NumpyBackend:

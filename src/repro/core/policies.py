@@ -65,7 +65,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.core import backend as backend_mod
-from repro.core.backend import gap_index, get_backend, transfer_counts
+from repro.core.backend import (gap_index, get_backend, put_slabs,
+                                transfer_counts, unpack_slabs)
 from repro.core.hw import NPUSpec, get_npu, with_sa_width
 from repro.core.opgen import (Op, StackedTrace, TraceArrays, Workload,
                               compile_trace, segment_sum, segmented_gaps,
@@ -1617,13 +1618,19 @@ _KERNELS: dict[str, object] = {}
 
 def _backend_kernel(bk):
     """The (possibly jitted) single-device sweep kernel for one
-    backend, its outputs packed by ``_pack``."""
+    backend, its outputs packed by ``_pack``. ``layouts`` (static) is
+    the ``(data, knobs)`` pair of ``slab_layout``s where the inputs come
+    as ``put_slabs`` slabs, rebuilt here by ``unpack_slabs``; ``None``
+    for either where it comes as a dict of per-leaf arrays (the GSPMD
+    mesh path's op columns)."""
     fn = _KERNELS.get(bk.name)
     if fn is None:
-        def kern(data, knobs, policies):
+        def kern(data, knobs, policies, layouts=(None, None)):
+            data, knobs = (x if lay is None else unpack_slabs(x, lay)
+                           for x, lay in zip((data, knobs), layouts))
             return _pack(_sweep_kernel(data, knobs, policies, bk),
                          policies, bk.xp)
-        fn = bk.jit(kern, static_argnames=("policies",))
+        fn = bk.jit(kern, static_argnames=("policies", "layouts"))
         _KERNELS[bk.name] = fn
     return fn
 
@@ -1639,7 +1646,7 @@ def _shard_kernel(bk, mesh, policies, wl_axis, knob_axis):
     ``wl_axis`` (completed by in-kernel psums), unique (saw, delay)
     pairs and the knob grid sharded over ``knob_axis``; everything
     else replicated. Inputs must be padded to the axis sizes
-    (``_sharded_backend_data`` / ``_knob_arrays(pad_to=...)``)."""
+    (``_sharded_backend_data`` / ``_knob_columns(pad_to=...)``)."""
     key = (bk.name, id(mesh), policies, wl_axis, knob_axis)
     hit = _SHARD_KERNELS.get(key)
     if hit is not None and hit[0] is mesh:
@@ -1700,9 +1707,10 @@ def _host_columns(st: StackedTrace, npu: NPUSpec) -> tuple[dict,
 
     Only *raw* trace columns and per-NPU scalars — no service times, no
     occupancy: those are traced inside the kernel now, which is what
-    lets ``sa_width`` ride the knob axis. Per-NPU scalars enter as 0-d
-    arrays so swapping generations never retraces the compiled
-    program. Cached on the stack (spec-identity keyed)."""
+    lets ``sa_width`` ride the knob axis. Per-NPU scalars are values
+    of the float64 slab (``_backend_data``), or 0-d arrays on a mesh
+    path, so swapping generations never retraces the compiled program.
+    Cached on the stack (spec-identity keyed)."""
     key = ("host_columns", id(npu))
     hit = st._derived.get(key)
     if hit is not None and hit[0] is npu:
@@ -1756,31 +1764,39 @@ def _host_columns(st: StackedTrace, npu: NPUSpec) -> tuple[dict,
 
 
 def _put_tree(tree, bk):
-    if isinstance(tree, dict):
-        return {k: _put_tree(v, bk) for k, v in tree.items()}
-    return bk.asarray(tree)
+    """One ``regate.put`` of a host pytree, leaf by leaf: the mesh
+    paths, whose ``in_specs`` and shardings place each leaf."""
+    def put(t):
+        if isinstance(t, dict):
+            return {k: put(v) for k, v in t.items()}
+        return bk.asarray(t)
+
+    with bk.span("regate.put", lambda: transfer_counts(tree)):
+        return put(tree)
 
 
 def _backend_data(st: StackedTrace, npu: NPUSpec, bk) \
-        -> tuple[dict, np.ndarray]:
-    """``_host_columns`` transferred to the backend once and cached on
-    the stack (spec-identity keyed, same convention as ``_batch_ctx``)."""
+        -> tuple[tuple, np.ndarray]:
+    """``_host_columns`` transferred to the backend once, as one float64
+    and one int64 slab (``put_slabs``: ``(layout, slabs)``), and cached
+    on the stack (spec-identity keyed, same convention as
+    ``_batch_ctx``)."""
     key = ("backend_data", bk.name, id(npu))
     hit = st._derived.get(key)
     if hit is not None and hit[0] is npu:
         return hit[1], hit[2]
     with bk.span("regate.host_columns"):
         host, sram_setpm = _host_columns(st, npu)
-    with bk.span("regate.put", lambda: transfer_counts(host)):
-        data = _put_tree(host, bk)
+    data = put_slabs(host, bk)
     st._derived[key] = (npu, data, sram_setpm)
     return data, sram_setpm
 
 
 def _sharded_backend_data(st: StackedTrace, npu: NPUSpec, bk,
                           wl_size: int) -> tuple[dict, np.ndarray]:
-    """``_backend_data`` with the op axis padded to a multiple of the
-    ``wl`` mesh-axis size so ``shard_map`` can split it evenly.
+    """``_backend_data``'s columns put leaf by leaf (``_put_tree``), for
+    the mesh paths that shard them, with the op axis padded to a
+    multiple of the ``wl`` mesh-axis size so it splits evenly.
 
     Padded ops are inert by construction: count 0, no FLOPs/bytes (so
     never active, zero duration), sentinel 1×1×1 matmul dims with
@@ -1805,9 +1821,7 @@ def _sharded_backend_data(st: StackedTrace, npu: NPUSpec, bk,
             else:
                 v = fill.get(k, 0.0)
             op[k] = np.concatenate([a, np.full(pad, v, a.dtype)])
-    host = {**host, "op": op}
-    with bk.span("regate.put", lambda: transfer_counts(host)):
-        data = _put_tree(host, bk)
+    data = _put_tree({**host, "op": op}, bk)
     st._derived[key] = (npu, data, sram_setpm)
     return data, sram_setpm
 
@@ -1816,7 +1830,7 @@ def knob_pairs(knob_grid) -> "tuple[list[tuple], np.ndarray]":
     """Unique (sa_width, delay_scale, window_scale) triples of a knob
     grid and the knob -> triple inverse map — the axes the executors
     actually see (leak knobs are post-hoc linear and never change
-    machine behavior). The host-side twin of ``_knob_arrays``'s
+    machine behavior). The host-side twin of ``_knob_columns``'s
     unique-pair dedup, shared with the batched program plane
     (``repro.core.program_plane``): knob points differing only in leak
     ratios map onto one executor row."""
@@ -1832,11 +1846,11 @@ def knob_pairs(knob_grid) -> "tuple[list[tuple], np.ndarray]":
     return trips, inv
 
 
-def _knob_arrays(knob_grid, npu: NPUSpec, bk, pad_to: int = 0) -> dict:
-    """Knob-grid arrays for the kernel: the full per-knob columns plus
-    the unique (sa_width, delay_scale, window_scale) triples the heavy
-    passes vmap over, with the inverse index mapping them back onto
-    the grid.
+def _knob_columns(knob_grid, npu: NPUSpec, pad_to: int = 0) -> dict:
+    """Knob-grid host arrays for the kernel: the full per-knob columns
+    plus the unique (sa_width, delay_scale, window_scale) triples the
+    heavy passes vmap over, with the inverse index mapping them back
+    onto the grid.
     ``pad_to`` pads the knob and pair axes to a multiple (repeating
     entry 0) so ``shard_map`` can split them evenly — the host slices
     the padded tail off the outputs."""
@@ -1895,8 +1909,14 @@ def _knob_arrays(knob_grid, npu: NPUSpec, bk, pad_to: int = 0) -> dict:
         "pair_wscale": pair_ws,
         "pair_inv": inv,
     }
-    with bk.span("regate.put", lambda: transfer_counts(host)):
-        return _put_tree(host, bk)
+    return host
+
+
+def _knob_arrays(knob_grid, npu: NPUSpec, bk) -> tuple[tuple, dict]:
+    """``_knob_columns`` put as one float64 and one int64 slab
+    (``put_slabs``: ``(layout, slabs)``): every path but ``shard_map``'s,
+    whose ``in_specs`` shard each knob leaf (``_put_tree``)."""
+    return put_slabs(_knob_columns(knob_grid, npu), bk)
 
 
 def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
@@ -1943,25 +1963,29 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
             if knob_axis is not None:
                 data, sram_setpm = _sharded_backend_data(st, npu, bk,
                                                          wl_size)
-                knobs = _knob_arrays(knob_grid, npu, bk,
-                                     pad_to=knob_size)
+                knobs = _put_tree(_knob_columns(knob_grid, npu,
+                                                pad_to=knob_size), bk)
                 kern = _shard_kernel(bk, mesh, policies, wl_axis,
                                      knob_axis)
                 with bk.span("regate.sweep_kernel"):
                     slab = bk.block(kern(data, knobs))
             else:
                 if mesh is None:
-                    data, sram_setpm = _backend_data(st, npu, bk)
+                    # one slab per dtype: at most 2 puts, cached
+                    (data_layout, data), sram_setpm = _backend_data(
+                        st, npu, bk)
                 else:
                     # the op axis must divide the "wl" axis: pad it
-                    # with inert ops, exactly as the shard_map path does
+                    # with inert ops, exactly as the shard_map path does,
+                    # and shard it leaf by leaf
                     data, sram_setpm = _sharded_backend_data(
                         st, npu, bk, wl_size)
-                    data = bk.shard_data(data, mesh)
-                knobs = _knob_arrays(knob_grid, npu, bk)
+                    data, data_layout = bk.shard_data(data, mesh), None
+                knob_layout, knobs = _knob_arrays(knob_grid, npu, bk)
                 kern = _backend_kernel(bk)
                 with bk.span("regate.sweep_kernel"):
-                    slab = bk.block(kern(data, knobs, policies))
+                    slab = bk.block(kern(data, knobs, policies,
+                                         (data_layout, knob_layout)))
 
             # one pull per NPU: (n_out, K_pad, W), shard padding dropped.
             # Beside the transfer, the span counts the work of the call

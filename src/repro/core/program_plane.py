@@ -50,7 +50,8 @@ from typing import Iterable, Optional, Sequence
 import numpy as np
 
 from repro.core import session
-from repro.core.backend import get_backend, transfer_counts
+from repro.core.backend import (get_backend, put_slabs, transfer_counts,
+                                unpack_slabs)
 from repro.core.hw import NPUSpec, get_npu, with_sa_width
 from repro.core.isa import events_to_arrays, scaled_delay, scaled_window
 from repro.core.lowering import (COMP_OF_UNIT, REGATE_FULL_TIMELINE,
@@ -255,12 +256,32 @@ def _kernel_body(data, xp):
     return init, gap_account, step
 
 
+# the dense leaves whose last axis is the unit axis (U = 4). They ride
+# their slab unit-major, last two axes swapped: the chip tiles an
+# array's two minor axes, and a 4-wide minor axis rebuilt from a flat
+# slab pads to the tile's 128 lanes (32x the bytes, and over a minute
+# of compile at the paper suite's stack)
+_UNIT_MINOR = ("lat", "pm", "delay", "window", "mode0")
+
+
+def _unit_major(d: dict) -> dict:
+    """``d`` with the last two axes of its ``_UNIT_MINOR`` leaves
+    swapped: the order they are put in, and back (its own inverse)."""
+    return {k: v.swapaxes(-1, -2) if k in _UNIT_MINOR else v
+            for k, v in d.items()}
+
+
 def _full_body(bk):
     """The jit'able whole-stack program: scan over the event axis, then
-    the ``run()`` tail gap to the horizon and ``_finish``'s drain."""
+    the ``run()`` tail gap to the horizon and ``_finish``'s drain.
+    ``layout`` (static) is the ``slab_layout`` where ``d`` comes as the
+    ``put_slabs`` slabs of ``_unit_major(d)``, ``None`` where it comes as
+    the per-leaf ``_pack_dense`` dict (the mesh path)."""
     xp = bk.xp
 
-    def body(d):
+    def body(d, layout=None):
+        if layout is not None:
+            d = _unit_major(unpack_slabs(d, layout))
         init, gap_account, step = _kernel_body(d, xp)
         st = bk.scan(step, init,
                      {"cycle": d["cycle"], "lat": d["lat"],
@@ -280,7 +301,7 @@ def _full_body(bk):
 def _compiled(bk):
     fn = _KERNELS.get(bk.name)
     if fn is None:
-        fn = bk.jit(_full_body(bk))
+        fn = bk.jit(_full_body(bk), static_argnames=("layout",))
         _KERNELS[bk.name] = fn
     return fn
 
@@ -294,14 +315,14 @@ def _scan_counts(data: dict) -> dict:
 
 
 def _run_kernel(data: dict, bk) -> dict[str, np.ndarray]:
-    """Execute the packed event stack on the backend; returns host
-    numpy outputs per row."""
+    """Execute the packed event stack on the backend, put unit-major as
+    one int64 and one int8 (``pm``) slab; returns host numpy outputs per
+    row."""
     fn = _compiled(bk)
     with bk.compute_scope():
-        with bk.span("regate.put", lambda: transfer_counts(data)):
-            dev = {k: bk.asarray(v) for k, v in data.items()}
+        layout, dev = put_slabs(_unit_major(data), bk)
         with bk.span("regate.scan_kernel", lambda: _scan_counts(data)):
-            out = bk.block(fn(dev))
+            out = bk.block(fn(dev, layout=layout))
     with bk.span("regate.harvest", lambda: transfer_counts(out)):
         return {k: bk.to_numpy(v) for k, v in out.items()}
 

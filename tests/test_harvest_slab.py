@@ -43,16 +43,19 @@ def _mesh(axes):
 
 
 def _count_pulls(monkeypatch, name: str) -> dict:
-    """Count the ``to_numpy`` calls of the cached backend ``name``."""
+    """Count the ``to_numpy`` calls of the backend ``name``. The class is
+    patched, not the cached instance: undoing an instance patch would
+    leave a bound method on the instance, shadowing later class
+    patches of other tests in the process."""
     bk = get_backend(name)
     made = {"pulls": 0}
     pull = bk.to_numpy
 
-    def to_numpy(x):
+    def to_numpy(self, x):
         made["pulls"] += 1
         return pull(x)
 
-    monkeypatch.setattr(bk, "to_numpy", to_numpy)
+    monkeypatch.setattr(type(bk), "to_numpy", to_numpy)
     return made
 
 
@@ -88,23 +91,27 @@ def _programs(axes, bk, policies):
     def raw(data, knobs):
         return pol_mod._sweep_kernel(data, knobs, policies, bk)
 
+    knob_host = pol_mod._knob_columns(GRID, npu)
     if axes is None:
-        data, _ = pol_mod._backend_data(st, npu, bk)
-        knobs = pol_mod._knob_arrays(GRID, npu, bk)
+        (data_layout, data), _ = pol_mod._backend_data(st, npu, bk)
+        knob_layout, knobs = pol_mod._knob_arrays(GRID, npu, bk)
         kern = pol_mod._backend_kernel(bk)
-        return (lambda d, k: kern(d, k, policies)), jax.jit(raw), \
-            (data, knobs)
+        host, _ = pol_mod._host_columns(st, npu)
+        return (lambda d, k: kern(d, k, policies,
+                                  (data_layout, knob_layout))), \
+            (lambda d, k: jax.jit(raw)(host, knob_host)), (data, knobs)
     sizes = bk.mesh_axis_sizes(mesh)
     data, _ = pol_mod._sharded_backend_data(st, npu, bk, sizes["wl"]
                                             if "wl" in sizes else 1)
     if axes == ("wl",):
         data = bk.shard_data(data, mesh)
-        knobs = pol_mod._knob_arrays(GRID, npu, bk)
+        knob_layout, knobs = pol_mod._knob_arrays(GRID, npu, bk)
         kern = pol_mod._backend_kernel(bk)
-        return (lambda d, k: kern(d, k, policies)), jax.jit(raw), \
-            (data, knobs)
+        return (lambda d, k: kern(d, k, policies, (None, knob_layout))), \
+            (lambda d, k: jax.jit(raw)(d, knob_host)), (data, knobs)
     wl_axis = "wl" if "wl" in sizes else None
-    knobs = pol_mod._knob_arrays(GRID, npu, bk, pad_to=sizes["knob"])
+    knobs = pol_mod._put_tree(
+        pol_mod._knob_columns(GRID, npu, pad_to=sizes["knob"]), bk)
     packed = pol_mod._shard_kernel(bk, mesh, policies, wl_axis, "knob")
     spec = bk.pspec
     data_spec = {"op": spec(wl_axis) if wl_axis else spec(),
@@ -144,8 +151,8 @@ def test_layout_covers_every_kernel_output():
     st = stack_traces(paper_suite()[:2])
     npu = get_npu("NPU-D")
     for policies in (tuple(POLICIES), ("NoPG",), ("ReGate-HW", "Ideal")):
-        data, _ = pol_mod._backend_data(st, npu, bk)
-        knobs = pol_mod._knob_arrays(GRID, npu, bk)
+        data, _ = pol_mod._host_columns(st, npu)
+        knobs = pol_mod._knob_columns(GRID, npu)
         out = pol_mod._sweep_kernel(data, knobs, policies, bk)
         paths = []
 

@@ -19,13 +19,15 @@ import pytest
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from chip_smoke import PLANE_GRID, PLANE_NPUS, SWEEP_AXES  # noqa: E402
-from repro.core.backend import get_backend  # noqa: E402
+from repro.core.backend import (get_backend, pack_slabs,  # noqa: E402
+                                slab_layout)
 from repro.core.hw import get_npu  # noqa: E402
 from repro.core.opgen import paper_suite, stack_traces  # noqa: E402
 from repro.core.policies import (POLICIES, KnobGrid,  # noqa: E402
                                  _backend_kernel, _host_columns,
-                                 _knob_arrays, knob_pairs)
-from repro.core.program_plane import _compiled, _exec_rows  # noqa: E402
+                                 _knob_columns, knob_pairs)
+from repro.core.program_plane import (_compiled, _exec_rows,  # noqa: E402
+                                      _unit_major)
 from repro.parallel import jax_compat  # noqa: E402
 
 
@@ -61,17 +63,26 @@ def _shapes(tree, sharding):
     return jax.tree_util.tree_map(sds, tree)
 
 
+def _slabs(tree):
+    """A host pytree as the kernels take it: its ``slab_layout`` and
+    the slabs ``put_slabs`` would put."""
+    layout = slab_layout(tree)
+    return layout, pack_slabs(tree, layout)
+
+
 def test_sweep_kernel_compiles_for_one_v5e(one_chip):
     st = stack_traces(paper_suite())
     npu = get_npu("NPU-D")
     host, _ = _host_columns(st, npu)
-    knobs = _knob_arrays(KnobGrid(**SWEEP_AXES).product(), npu,
-                         get_backend("numpy"))
+    data_layout, data = _slabs(host)
+    knob_layout, knobs = _slabs(
+        _knob_columns(KnobGrid(**SWEEP_AXES).product(), npu))
     kern = _backend_kernel(get_backend("jax"))
     with jax_compat.enable_x64():
-        compiled = kern.lower(_shapes(host, one_chip),
+        compiled = kern.lower(_shapes(data, one_chip),
                               _shapes(knobs, one_chip),
-                              tuple(POLICIES)).compile()
+                              tuple(POLICIES),
+                              (data_layout, knob_layout)).compile()
     assert compiled.memory_analysis() is not None
     assert "f64" in compiled.as_text() or "F64" in compiled.as_text()
 
@@ -81,7 +92,12 @@ def test_event_scan_kernel_compiles_for_one_v5e(one_chip):
     _, _, data = _exec_rows(paper_suite(),
                             [get_npu(n) for n in PLANE_NPUS], triples)
     assert data["cycle"].dtype == np.int64
+    layout, slabs = _slabs(_unit_major(data))
+    assert sorted(slabs) == ["int64", "int8"]
     with jax_compat.enable_x64():
         compiled = _compiled(get_backend("jax")).lower(
-            _shapes(data, one_chip)).compile()
-    assert compiled.memory_analysis() is not None
+            _shapes(slabs, one_chip), layout=layout).compile()
+    mem = compiled.memory_analysis()
+    # the unit axis rebuilt minor from a flat slab pads 32x: 0.5 GB of
+    # temporaries here, against none with the slabs put unit-major
+    assert mem.temp_size_in_bytes <= mem.argument_size_in_bytes
