@@ -6,8 +6,8 @@ allowed fraction (default 30%) — the speedup gates stop being
 upload-only artifacts and start failing PRs.
 
   python -m benchmarks.check_regression \
-      BENCH_policy_engine.json:BENCH_policy_engine.new.json \
       BENCH_timeline_executor.json:BENCH_timeline_executor.new.json \
+      BENCH_program_plane.json:BENCH_program_plane.new.json \
       [--metric speedup] [--max-regression 0.30]
 
 Each positional argument is ``baseline:fresh``. With no positional
@@ -26,11 +26,8 @@ import sys
 # new benchmarks register here so `python -m benchmarks.check_regression`
 # with no arguments covers the full set
 DEFAULT_PAIRS = [
-    "BENCH_policy_engine.json:BENCH_policy_engine.new.json",
     "BENCH_timeline_executor.json:BENCH_timeline_executor.new.json",
     "BENCH_program_plane.json:BENCH_program_plane.new.json",
-    "BENCH_sweep.json:BENCH_sweep.new.json",
-    "BENCH_sweep_jax.json:BENCH_sweep_jax.new.json",
     "BENCH_sweep_multidevice.json:BENCH_sweep_multidevice.new.json",
     "BENCH_perturb.json:BENCH_perturb.new.json",
     "BENCH_fleet.json:BENCH_fleet.new.json",

@@ -4,12 +4,12 @@ Simulates a 24h day on a 4096-chip fleet — 4 tenant classes, ~2.3M
 requests, 96 serving epochs, a 24-point knob grid, 3 congestion levels —
 and gates the one-batched-call-per-epoch design: ``sweep_fleet``'s
 epoch rate must be >= 10x a per-cell reference that evaluates the same
-epochs through the original ``evaluate`` loop (one policy-engine
-round-trip per (workload, policy, knob) cell, the ``sweep_reference``
-discipline). The reference only replays ``REF_EPOCHS`` epochs — at
-per-cell speed the full day would dominate CI — and is scaled to an
-epochs/sec rate on identical epoch inputs (``keep_epoch_inputs``), so
-both sides price exactly the same evaluation work.
+epochs through an ``evaluate`` loop (one one-cell sweep-kernel call
+on the host per (workload, policy, knob) cell). The reference only
+replays ``REF_EPOCHS`` epochs — at per-cell speed the full day would
+dominate CI — and is scaled to an epochs/sec rate on identical epoch
+inputs (``keep_epoch_inputs``), so both sides price exactly the same
+evaluation work.
 
 Writes ``BENCH_fleet.json`` (registered in ``check_regression``).
 

@@ -4,7 +4,7 @@ Times ``sweep_program_plane`` — the full paper suite x two NPU
 generations x a 4-point BET/window knob grid, executed through the
 ``repro.core.program_plane`` array kernel — against the per-cell host
 oracle ``sweep_program_plane_reference`` (one ``EventTimeline`` run +
-one closed-form ``evaluate`` per cell, the pre-ISSUE-10 path).
+one scalar ``evaluate_reference`` per cell).
 
 Records are compared cell-for-cell BEFORE timing counts: executor-side
 fields (cycles, stalls, wakes, setpm counts) must match exactly,

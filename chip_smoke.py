@@ -1,8 +1,8 @@
 """Bring-up smoke run of the simulator's jax path on a TPU.
 
 Drives the jax path once, in this one process, through the public entry
-points with ``backend="jax"``, and checks every phase against the numpy
-oracle in the same run:
+points with ``backend="jax"``, and checks every phase against the same
+kernels' numpy instantiation in the same run:
 
 * sweep          ``sweep_grid``: ``paper_suite()`` plus qwen2.5-14b at
                  ``decode_32k`` x 5 NPUs x 5 policies x the 240-knob fine
@@ -15,10 +15,15 @@ oracle in the same run:
                  scenario cut to a few epochs, unguarded; summary
                  <= 1e-9 and no guard events.
 
+That checks that the jax backend computes what the backend-neutral
+kernels compute on the host. The independent check on the chip, against
+a plain reference that imports nothing of the program, is
+``bench/run.py``'s.
+
 With ``--chips 4`` it runs only the ``jax_mesh=`` paths instead (GSPMD
 ``sweep_mesh(wl=4)``, shard_map ``sweep_mesh(wl=2, knob=2)`` and the
 program plane on a ``("wl",)`` mesh of 4), each against the
-single-device jax run and the numpy oracle.
+single-device jax run and the numpy instantiation.
 
   python chip_smoke.py              # one chip
   python chip_smoke.py --chips 4    # the mesh paths on a 4-chip host
@@ -47,7 +52,6 @@ import jax  # noqa: E402
 from benchmarks.perf_fleet import GRID as FLEET_GRID  # noqa: E402
 from benchmarks.perf_fleet import build_scenario  # noqa: E402
 from benchmarks.perf_program_plane import GRID as PLANE_GRID  # noqa: E402
-from benchmarks.perf_sweep_jax import GRID as SWEEP_AXES  # noqa: E402
 from repro.configs.base import SHAPES, get_arch  # noqa: E402
 from repro.core.backend import get_backend  # noqa: E402
 from repro.core.hw import NPUS  # noqa: E402
@@ -60,6 +64,13 @@ from repro.core.sweep import (sweep_fleet, sweep_grid,  # noqa: E402
 from repro.parallel import jax_compat  # noqa: E402
 
 RTOL = 1e-9
+# the 240-point fine knob grid of the sweep phase (§6.5 axes)
+SWEEP_AXES = dict(
+    delay_scale=(0.25, 0.5, 1.0, 2.0, 4.0, 8.0),
+    leak_off_logic=(0.01, 0.03, 0.1, 0.2, 0.4),
+    leak_sram_sleep=(0.1, 0.25, 0.4, 0.6),
+    leak_sram_off=(0.002, 0.02),
+)
 SWEEP_SUBSAMPLE = 16  # every 16th knob of the 240-point grid is checked
 FLEET_EPOCHS = 8
 PLANE_NPUS = ("NPU-B", "NPU-D")
@@ -69,7 +80,7 @@ PLANE_CMP = {"exact": EXACT_FIELDS, "floor": 1.0}
 
 
 class Mismatch(AssertionError):
-    """The jax path disagrees with its reference."""
+    """The jax path disagrees with the kernels' numpy instantiation."""
 
 
 def max_rel_dev(ref: list[dict], got: list[dict],
@@ -143,7 +154,7 @@ def _knob_slice(res, idx: list[int]):
 
 def sweep_phase(workloads, npus, grid: KnobGrid, subsample: int) -> dict:
     """``sweep_grid`` on jax over the whole grid, then every
-    ``subsample``-th knob's records against the numpy oracle."""
+    ``subsample``-th knob's records against the numpy kernel."""
     t_first, t_steady, res = _timed(lambda: sweep_grid(
         workloads, npus, POLICIES, grid=grid, backend="jax",
         as_records=False))
@@ -212,7 +223,7 @@ def _require_span(seen: list, mesh, what: str) -> None:
 def mesh_phase(workloads, npus, knobs, plane_workloads, plane_npus,
                plane_knobs, n_dev: int = 4) -> dict[str, dict]:
     """The ``jax_mesh=`` paths over ``n_dev`` devices, each against the
-    single-device jax run (timed too) and the numpy oracle, each
+    single-device jax run (timed too) and the numpy kernel, each
     required to lay its kernel results on all ``n_dev`` devices."""
     if len(jax.devices()) < n_dev:
         raise RuntimeError(f"mesh phase needs {n_dev} devices, found "

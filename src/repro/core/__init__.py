@@ -4,15 +4,16 @@ hw/power      — NPU-A..E specs (Table 2/3) + calibrated power model
 sa_gating     — PE-level spatial SA gating (Figs 10-13)
 isa/passes    — setpm ISA extension + compiler passes (Figs 14-15, §4.3)
 opgen/policies— operator traces, columnar trace compilation, and the five
-                designs (§6): vectorized ``evaluate`` + scalar
-                ``evaluate_reference`` oracle
+                designs (§6): the scalar ``evaluate_reference`` oracle
+                and one backend-neutral sweep kernel that
+                ``evaluate``, ``evaluate_batch`` and every plane use
 sweep         — batched design-space sweeps (workloads × npus × policies
                 × knob grids): one ``evaluate_batch`` pass over the
                 stacked super-trace; ``sweep_reference`` loop oracle;
                 ``sweep_grid`` fine-knob §6.5 grids (100k-cell scale)
-backend       — pluggable array substrate for the batched plane: numpy
-                (oracle) or one jitted float64 jax program (≤1e-9
-                equivalent, reused across NPU generations)
+backend       — pluggable array substrate for the sweep kernel: numpy
+                (eager, on the host) or one jitted float64 jax program
+                (reused across NPU generations)
 carbon        — operational/embodied carbon (Figs 24-25)
 slo           — SLO-constrained config sweep (Fig 2)
 hlo/roofline  — compiled-HLO cost extraction for the dry-run

@@ -1,11 +1,12 @@
 """Pluggable array backend for the batched sweep plane (ISSUE 4).
 
-The batched policy engine (``repro.core.policies.evaluate_batch``) is a
-handful of segmented array passes over a stacked super-trace. This
-module abstracts the array substrate those passes run on so the same
-backend-neutral kernel executes either on
+The policy engine (``repro.core.policies.evaluate_batch``, and
+``evaluate`` as its one-cell call) is a handful of segmented array
+passes over a stacked super-trace. This module abstracts the array
+substrate those passes run on so the same backend-neutral kernel
+executes either on
 
-* **numpy** — eager, always available, and the production oracle; or
+* **numpy** — the kernel eagerly on the host, always available; or
 * **jax**   — one ``jax.jit``-compiled program (knob axis via ``vmap``,
   segmented reductions via ``jax.ops.segment_sum``), reused across NPU
   generations because every per-generation quantity enters as a traced
@@ -52,7 +53,7 @@ plain ``segment_sum`` over per-op idle time and the per-knob threshold
 masking stays shape-stable inside the compiled program.
 
 The jax backend requires float64 (the ≤1e-9 record equivalence against
-the numpy oracle is meaningless at f32): entry points run inside
+the scalar oracle is meaningless at f32): entry points run inside
 ``compute_scope()``, which enables x64 locally through
 ``parallel.jax_compat.enable_x64`` and raises a clear error if arrays
 still come out narrower.
@@ -173,7 +174,10 @@ def put_slabs(tree, bk) -> tuple[tuple, dict]:
 
 
 class NumpyBackend:
-    """Eager numpy instantiation of the backend contract (the oracle)."""
+    """Eager numpy instantiation of the backend contract: the sweep
+    kernel on the host (``evaluate``, and ``evaluate_batch`` by
+    default). The oracle it is checked against is the scalar
+    ``policies.evaluate_reference``."""
 
     name = "numpy"
     xp = np
@@ -428,7 +432,7 @@ def failover_rungs(name: Optional[str] = None, jax_mesh=None) \
         -> tuple[tuple[str, object], ...]:
     """The guard plane's backend-downgrade ladder for a requested
     (backend, mesh): each rung is ``(rung_name, mesh)``, ordered from
-    the requested substrate down to the numpy oracle —
+    the requested substrate down to the host's numpy kernel —
     ``jax-mesh`` → ``jax`` (single device) → ``numpy``. A numpy
     request has nowhere to fall, so its ladder is just itself.
     ``None`` resolves through the active session, mirroring

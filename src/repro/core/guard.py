@@ -29,15 +29,15 @@ discipline the NPUs get:
   abandoned, not waited on). On timeout / compile failure / device
   loss it retries with exponential backoff + deterministic seeded
   jitter, then escalates down ``backend.failover_rungs``: jax-mesh →
-  jax single-device → the numpy oracle. Every escalation lands in a
-  structured :class:`GuardReport` event with a named reason —
-  mirroring the fleet plane's own degradation ladder, but for the
-  harness.
+  jax single-device → the numpy backend (the same sweep kernel on the
+  host). Every escalation lands in a structured :class:`GuardReport`
+  event with a named reason — mirroring the fleet plane's own
+  degradation ladder, but for the harness.
 
 * **Numerical quarantine** — every result cube is finite-checked. If
   any cell is NaN/Inf, the poisoned cells are quarantined and
-  re-evaluated per-cell on the numpy oracle, and every surviving cell
-  must match a full oracle re-run to ``oracle_tol`` (≤1e-9) — silent
+  re-evaluated per-cell on the numpy backend, and every surviving cell
+  must match a full numpy re-run to ``oracle_tol`` (≤1e-9) — silent
   corruption becomes a loud, attributable :class:`GuardError` or a
   recorded quarantine event, never a wrong BET frontier.
 
@@ -100,7 +100,7 @@ class GuardPolicy:
                           (1 + backoff_jitter * u)`` with ``u`` drawn
                           from the seeded guard stream (deterministic).
     ``oracle_tol``      — max relative error a surviving cell may show
-                          vs the numpy oracle during quarantine.
+                          vs the numpy backend during quarantine.
     ``checkpoint_every``— epochs between published snapshots (the
                           final epoch always publishes).
     """
@@ -644,7 +644,7 @@ class GuardedRunner:
                 f"non-finite {','.join(poisoned)} from rung {rung!r} "
                 f"at cell (workload={wl_names[w]}, npu={a}, "
                 f"policy={policies[p]}, knob={k}); re-evaluated on "
-                f"the numpy oracle",
+                f"the numpy backend",
                 step=int(step), rung=rung,
                 cell=[int(w), int(a), int(p), int(k)],
                 fields=poisoned)
@@ -660,7 +660,7 @@ class GuardedRunner:
                 w, a, p, k = [int(i[0]) for i in
                               np.nonzero(~np.isfinite(oarr))]
                 raise GuardError(
-                    f"numpy oracle itself is non-finite in {name} at "
+                    f"numpy backend itself is non-finite in {name} at "
                     f"cell (workload={wl_names[w]}, npu={a}, policy="
                     f"{policies[p]}, knob={k}) — the model, not the "
                     f"backend, is poisoned")
@@ -671,7 +671,7 @@ class GuardedRunner:
                 worst_ix[ok] = _rel_err(arr, oarr)[ok] == err.max()
                 w, a, p, k = [int(i[0]) for i in np.nonzero(worst_ix)]
                 raise GuardError(
-                    f"surviving cell disagrees with the numpy oracle "
+                    f"surviving cell disagrees with the numpy backend "
                     f"beyond {tol:g}: {name} at (workload="
                     f"{wl_names[w]}, npu={a}, policy={policies[p]}, "
                     f"knob={k}) rel err {float(err.max()):.3e} — rung "
@@ -699,7 +699,7 @@ class GuardedRunner:
         self.report.add(
             "oracle_recheck",
             f"quarantined {len(cells)} cell(s) from rung {rung!r}; "
-            f"survivors match the numpy oracle to "
+            f"survivors match the numpy backend to "
             f"{max(worst, 0.0):.3e} (tol {tol:g})",
             step=int(step), rung=rung, n_quarantined=len(cells),
             max_survivor_rel_err=worst)

@@ -123,11 +123,11 @@ def get_npu(name: str) -> NPUSpec:
     raise KeyError(f"unknown NPU {name!r}; have {sorted(NPUS)}")
 
 
-# SA-width variants memoized by (base spec identity, width): the policy
-# engine's derived-trace caches (``trace_times``, ``_batch_ctx``,
-# ``_backend_data``) are keyed by spec identity, so the knob axis must
-# hand back the SAME variant object on every call or each sweep would
-# re-derive and re-transfer its arrays. The value keeps a strong ref to
+# SA-width variants memoized by (base spec identity, width): the
+# derived-trace caches of the oracle, lowering and the program plane
+# (``trace_times``, lowered programs) are keyed by spec identity, so the
+# knob axis must hand back the SAME variant object on every call or each
+# sweep would re-derive its arrays. The value keeps a strong ref to
 # the base spec so its id cannot be reused. The variant keeps the base
 # *name* — every name-keyed table (power shares, figures) applies
 # unchanged, and sweep records carry the width in their own

@@ -19,12 +19,14 @@ This module bridges them at workload scale:
    burst model (shared with the policy engine: per-burst holes are
    sub-cycle-schedule detail in both planes).
 4. ``crossval_record`` compares the resulting per-component gated-cycle
-   fractions and setpm counts against ``policies.evaluate``'s
-   ``ReGate-Full`` (sw) report. Tolerances are stated in EXPERIMENTS.md
-   §Program-plane; the deviations are the transition-edge accounting
-   (executor gates ``gap - delay`` where the closed form charges
-   ``gap - 2*delay``) and merged within-op slack on the hw-managed
-   components.
+   fractions and setpm counts against the scalar oracle
+   ``policies.evaluate_reference``'s ``ReGate-Full`` (sw) report (not
+   the sweep kernel, so the batched program plane is held to an engine
+   independent of the one it runs). Tolerances are stated in
+   EXPERIMENTS.md §Program-plane; the deviations are the transition-edge
+   accounting (executor gates ``gap - delay`` where the closed form
+   charges ``gap - 2*delay``) and merged within-op slack on the
+   hw-managed components.
 
 Scheduling model (mirrors the policy engine's timing semantics): ops run
 back-to-back; per op, each component is busy for its own service time at
@@ -49,7 +51,7 @@ from repro.core.passes import (IdleInterval, SetpmPlacement, SlotUse,
                                analyze_vu_idleness, instrument_setpm,
                                should_gate)
 from repro.core.policies import (PolicyKnobs, _component_policies,
-                                 _fine_grained_vu_vec, evaluate,
+                                 _fine_grained_vu_vec, evaluate_reference,
                                  knob_columns, trace_times)
 
 # component -> (unit name, FU kind) in the lowered program
@@ -462,12 +464,13 @@ def crossval_record(wl: Workload, npu: NPUSpec | str = "NPU-D",
                     knobs: Optional[PolicyKnobs] = None,
                     knob_idx: int = 0) -> dict:
     """One flat record comparing the program plane against the
-    closed-form ``ReGate-Full`` (sw) policy evaluation, at one knob
-    point (lowering, instrumentation, and trace compilation all ride
-    their identity caches, so repeated sweeps stop re-lowering)."""
+    closed-form ``ReGate-Full`` (sw) policy evaluation of the scalar
+    oracle ``evaluate_reference``, at one knob point (lowering,
+    instrumentation, and trace compilation all ride their identity
+    caches, so repeated sweeps stop re-lowering)."""
     npu = get_npu(npu) if isinstance(npu, str) else npu
     knobs = knobs if knobs is not None else PolicyKnobs()
-    rep = evaluate(wl, npu, "ReGate-Full", knobs)
+    rep = evaluate_reference(wl, npu, "ReGate-Full", knobs)
     prog = lower_workload(wl, with_sa_width(npu, knobs.sa_width))
     summ = execute_program(prog, knobs=knobs)
     return plane_record(

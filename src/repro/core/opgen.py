@@ -278,34 +278,10 @@ def stack_traces(workloads) -> StackedTrace:
     return st
 
 
-def segment_sum(arr: np.ndarray, offsets: np.ndarray) -> np.ndarray:
-    """Per-segment row sums: ``arr`` (N, ...) -> (W, ...) for the ragged
-    segmentation ``offsets`` (W+1,).
-
-    Empty segments sum to zero (``np.add.reduceat`` alone mishandles
-    degenerate bounds). Within a segment the accumulation is
-    left-to-right, matching the scalar engines' sequential ``+=`` order.
-    ``offsets`` is coerced to int64, so callers may pass any integral
-    dtype (or a Python list) without tripping ``reduceat``.
-    """
-    offsets = np.asarray(offsets, np.int64)
-    n_seg = len(offsets) - 1
-    out = np.zeros((n_seg,) + arr.shape[1:], np.float64)
-    if n_seg == 0 or arr.shape[0] == 0:
-        return out
-    starts = offsets[:-1]
-    nonempty = offsets[1:] > starts
-    if nonempty.any():
-        # empty segments span zero rows, so chunks between consecutive
-        # non-empty starts cover exactly one segment each
-        out[nonempty] = np.add.reduceat(arr, starts[nonempty], axis=0)
-    return out
-
-
 def segmented_gaps(active: np.ndarray, idle: np.ndarray,
                    offsets: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
-    """Merged idle-gap lengths per segment — the stacked counterpart of
-    the policy engine's per-workload ``_merged_gaps``.
+    """Merged idle-gap lengths per segment — the intervals the scalar
+    oracle's ``close_gap`` sees, for every workload of a stack.
 
     ``active``/``idle`` are per-op over the whole stack (idle holds
     dur*count where the component is inactive, 0 where active). Each

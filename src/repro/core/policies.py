@@ -22,44 +22,34 @@ op duration = max over components (perfect overlap); ops run back-to-back.
 Idle intervals per component are the within-op slack plus whole ops where
 the component is unused, merged across op boundaries.
 
-Two engines share these semantics:
+One oracle and one engine price these semantics:
 
-* ``evaluate`` — columnar: the workload is compiled once into
-  ``TraceArrays`` (struct-of-arrays), per-component service times and the
-  SA-occupancy math are batched over the whole op stream, idle-gap
-  merging is a segmented reduction, and ``_gated_idle_energy`` is applied
-  as a piecewise-vectorized closed form. This is the production path.
 * ``evaluate_reference`` — the original pure-Python per-op loop, kept as
-  the oracle; the equivalence tests hold the two to ≤1e-9 relative on
-  every EnergyReport field.
-
-A third layer batches whole design-space sweeps:
-
-* ``evaluate_batch`` — the sweep plane: stacks every workload trace into
-  one ragged super-trace (``opgen.stack_traces``), reuses per-(trace,
-  NPU) service times across the policy × knob axes, carries the knob
-  grid as a trailing array dimension, and memoizes per-component
-  results across policies that share a component configuration. One
-  call covers the full (workload × npu × policy × knob) cross product
-  in a handful of array passes; cell-for-cell ≤1e-9 relative to
-  ``evaluate``. Via ``backend="jax"`` the same sweep runs as one
-  ``jax.jit``-compiled float64 program (``repro.core.backend``): gap
-  chunking moves to a host-built fixed-shape index, per-NPU numbers
-  enter as traced arrays so one compiled program serves every
-  generation, and — since ISSUE 5 — the per-op service times and SA
-  PE-occupancy math are *traced* too (``bk.sa_occupancy``; SA width is
-  a real ``PolicyKnobs.sa_width`` knob axis). Heavy O(n_ops) work is
-  vmapped over the unique SA widths and the unique (width, delay)
-  pairs with the leakage knobs folded in linearly afterwards. A
-  ``jax_mesh`` scales the program out across devices — GSPMD op-axis
-  sharding on a ``("wl",)`` mesh, or an explicit ``shard_map`` SPMD
-  program when the mesh has a ``"knob"`` axis — record-for-record
-  ≤1e-9 against the numpy path, which stays the oracle.
+  the oracle; the equivalence tests hold the engine to ≤1e-9 relative
+  on every EnergyReport field.
+* ``_sweep_kernel`` — one backend-neutral program over the whole
+  (workload × npu × policy × knob) cross product. ``evaluate_batch``
+  stacks every workload trace into one ragged super-trace
+  (``opgen.stack_traces``) and runs the kernel once per NPU on the
+  chosen backend (``repro.core.backend``): eagerly on the host with
+  ``backend="numpy"``, or as one ``jax.jit``-compiled float64 program
+  with ``backend="jax"``. Gap merging uses a host-built fixed-shape
+  chunk index, per-NPU numbers enter as arrays so one compiled program
+  serves every generation, and the per-op service times and SA
+  PE-occupancy math run inside the kernel (``bk.sa_occupancy``), so SA
+  width is a real ``PolicyKnobs.sa_width`` knob axis. Heavy O(n_ops)
+  work runs once per unique SA width and unique (width, delay, window)
+  triple, with the leakage knobs folded in linearly afterwards; results
+  are memoized per distinct component policy. ``evaluate`` is the
+  one-cell call of ``evaluate_batch`` on numpy, and every plane
+  (sweeps, program plane, fleet) goes through ``evaluate_batch``. A
+  ``jax_mesh`` scales the jax program out across devices — GSPMD
+  op-axis sharding on a ``("wl",)`` mesh, or an explicit ``shard_map``
+  SPMD program when the mesh has a ``"knob"`` axis.
 """
 from __future__ import annotations
 
-import math
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
 import numpy as np
@@ -69,7 +59,6 @@ from repro.core.backend import (gap_index, get_backend, put_slabs,
                                 transfer_counts, unpack_slabs)
 from repro.core.hw import NPUSpec, get_npu, with_sa_width
 from repro.core.opgen import (Op, StackedTrace, TraceArrays, Workload,
-                              compile_trace, segment_sum, segmented_gaps,
                               stack_traces)
 from repro.core.power import COMPONENTS, PowerModel
 from repro.core.sa_gating import SAStats, gating_stats, gating_stats_batch
@@ -84,13 +73,13 @@ class PolicyKnobs:
     """Sensitivity-analysis overrides (paper §6.5).
 
     ``sa_width`` overrides the NPU's systolic-array width (``None`` →
-    native). It is a real knob axis: the scalar engines evaluate on a
-    memoized ``hw.with_sa_width`` variant spec, the numpy batched plane
-    groups the knob grid by effective width, and the jax sweep kernel
-    carries the width as a *traced* scalar so one compiled program
-    serves the whole width axis. Note SA peak FLOP/s is derived from
-    the width, so this axis moves throughput and occupancy together —
-    the paper's §6.5 width sensitivity, without per-width NPU variants.
+    native). It is a real knob axis: the scalar oracle evaluates on a
+    memoized ``hw.with_sa_width`` variant spec, and the sweep kernel
+    carries the width as an array value (traced on jax) so one
+    compiled program serves the whole width axis. Note SA peak FLOP/s
+    is derived from the width, so this axis moves throughput and
+    occupancy together — the paper's §6.5 width sensitivity, without
+    per-width NPU variants.
     """
     leak_off_logic: Optional[float] = None
     leak_sram_sleep: Optional[float] = None
@@ -565,7 +554,7 @@ def evaluate_reference(wl: Workload, npu: NPUSpec | str = "NPU-D",
 
 
 # --------------------------------------------------------------------------
-# evaluation — columnar vectorized engine
+# per-op columns on the host: lowering, figures, the program plane's folds
 # --------------------------------------------------------------------------
 
 def trace_times(tr: TraceArrays, npu: NPUSpec) -> dict[str, np.ndarray]:
@@ -611,184 +600,6 @@ def trace_times(tr: TraceArrays, npu: NPUSpec) -> dict[str, np.ndarray]:
     }
     tr._derived[id(npu)] = (npu, out)
     return out
-
-
-def _merged_gaps(active: np.ndarray, idle: np.ndarray) -> np.ndarray:
-    """Idle-gap lengths per maximal run of inactive ops.
-
-    ``idle`` holds dur*count where the component is inactive, 0 where
-    active. Returns one gap per active op (the merged idle time since the
-    previous active op) plus one trailing gap — exactly the intervals the
-    scalar engine's ``close_gap`` sees. Segment sums are accumulated
-    left-to-right via ``np.add.reduceat``, matching the scalar's
-    sequential ``pending +=`` order.
-    """
-    idx = np.flatnonzero(active)
-    if idx.size == 0:
-        return np.array([idle.sum()])
-    idle2 = np.append(idle, 0.0)
-    bounds = np.concatenate(([0], idx + 1))
-    return np.add.reduceat(idle2, bounds)
-
-
-def _gated_idle_energy_vec(gap: np.ndarray, p_static: float, *, mode: str,
-                           bet_s: float, delay_s: float, window_s: float,
-                           leak: float):
-    """Piecewise-vectorized ``_gated_idle_energy`` over an array of gaps.
-
-    Returns (energy_J, exposed_wake_s, wake_events, setpm, gated_s)
-    arrays.
-    """
-    pos = gap > 0
-    zeros = np.zeros_like(gap)
-    ungated = np.where(pos, p_static * gap, 0.0)
-    if mode == "none":
-        return ungated, zeros, zeros, zeros, zeros
-    if mode == "ideal":
-        return zeros, zeros, zeros, zeros, np.where(pos, gap, 0.0)
-    if mode == "hw":
-        g = pos & (gap > window_s)
-        e = np.where(g, p_static * window_s
-                     + leak * p_static * (gap - window_s)
-                     + p_static * delay_s, ungated)
-        gs = np.where(g, gap - window_s, 0.0)
-        return e, np.where(g, delay_s, 0.0), g.astype(np.float64), zeros, gs
-    # sw
-    g = pos & (gap >= max(bet_s, 2.0 * delay_s))
-    e = np.where(g, leak * p_static * (gap - 2 * delay_s)
-                 + p_static * 2 * delay_s, ungated)
-    gf = g.astype(np.float64)
-    return e, zeros, gf, 2.0 * gf, np.where(g, gap - 2 * delay_s, 0.0)
-
-
-def evaluate(wl: Workload, npu: NPUSpec | str = "NPU-D",
-             policy: str = "ReGate-Full",
-             knobs: PolicyKnobs = PolicyKnobs()) -> EnergyReport:
-    """Columnar engine; semantics identical to ``evaluate_reference``."""
-    npu = get_npu(npu) if isinstance(npu, str) else npu
-    npu = with_sa_width(npu, knobs.sa_width)
-    tr = compile_trace(wl)
-    tm = trace_times(tr, npu)
-    pm = PowerModel(npu)
-    g = npu.gating
-    cp = _component_policies(policy)
-
-    leak_logic = knobs.leak_off_logic if knobs.leak_off_logic is not None \
-        else g.leak_off_logic
-    leak_sleep = knobs.leak_sram_sleep if knobs.leak_sram_sleep is not None \
-        else g.leak_sram_sleep
-    leak_off = knobs.leak_sram_off if knobs.leak_sram_off is not None \
-        else g.leak_sram_off
-
-    static_w = pm.static_w
-    dyn_w = pm.dyn_max_w
-    cnt = tr.count
-    dur = tm["dur"]
-    durn = dur * cnt
-
-    static_j = {c: 0.0 for c in COMPONENTS}
-    dynamic_j = {c: 0.0 for c in COMPONENTS}
-    wakes = {c: 0.0 for c in COMPONENTS}
-    gated = {c: 0.0 for c in COMPONENTS}
-    setpm_by = {c: 0.0 for c in COMPONENTS}
-    overhead = 0.0
-
-    for c in ("sa", "vu", "hbm", "ici"):
-        pol = cp[c]
-        a = tm[c]
-        active = a > 0
-        p = static_w[c]
-        leak = max(leak_logic, g.leak_hbm_refresh) if c == "hbm" \
-            else leak_logic
-        bet_s = g.bet.get(pol.delay_key, 0) * knobs.delay_scale / npu.freq_hz
-        delay_s = g.on_off_delay.get(pol.delay_key, 0) * knobs.delay_scale \
-            / npu.freq_hz
-        window_s = bet_s * g.detection_window_frac * knobs.window_scale
-
-        # merged cross-op idle gaps (each closed once, not per instance)
-        gaps = _merged_gaps(active, np.where(active, 0.0, durn))
-        e, exposed, nw, sp, gs = _gated_idle_energy_vec(
-            gaps, p, mode=pol.mode, bet_s=bet_s, delay_s=delay_s,
-            window_s=window_s, leak=leak)
-        sj = float(e.sum())
-        ov = float(exposed.sum())
-        wk = float(nw.sum())
-        gt = float(gs.sum())
-        setpm_by[c] += float(sp.sum())
-
-        an = a[active]
-        cn = cnt[active]
-        # dynamic: proportional to useful work
-        if c == "sa":
-            dynamic_j[c] = dyn_w[c] * float(
-                (tr.flops_sa[active] / npu.sa_flops * cn).sum())
-        else:
-            dynamic_j[c] = dyn_w[c] * float((an * cn).sum())
-        # static during the active portion (SA: PE-occupancy weighted)
-        if c == "sa" and pol.spatial_sa:
-            occ = tm["frac_on"] + g.leak_pe_weight_on * tm["frac_w_on"] \
-                + leak_logic * tm["frac_off"]
-            if pol.mode == "ideal":
-                occ = tm["frac_on"]
-            occ = np.where(tr.has_mm, occ, 1.0)
-            sj += p * float((occ[active] * an * cn).sum())
-        else:
-            sj += p * float((an * cn).sum())
-        # within-op slack (per executed instance)
-        if c == "vu":
-            fv = _fine_grained_vu_vec(tm, tr, npu, pol, static_w["vu"],
-                                      leak_logic, knobs)
-            sj += fv["static_j"]
-            ov += fv["overhead"]
-            wk += fv["wakes"]
-            gt += fv["gated_s"]
-            setpm_by[c] += fv["setpm"]
-        else:
-            slack = np.where(active, dur - a, 0.0)
-            e2, exp2, nw2, sp2, gs2 = _gated_idle_energy_vec(
-                slack, p, mode=pol.mode, bet_s=bet_s, delay_s=delay_s,
-                window_s=window_s, leak=leak)
-            sj += float((e2 * cnt).sum())
-            ov += float((exp2 * cnt).sum())
-            wk += float((nw2 * cnt).sum())
-            gt += float((gs2 * cnt).sum())
-            setpm_by[c] += float((sp2 * cnt).sum())
-        if c in ("hbm", "ici"):
-            # wake overlapped with the long DMA issue latency half the time
-            ov *= 0.5
-        static_j[c] = sj
-        wakes[c] = wk
-        gated[c] = gt
-        overhead += ov
-
-    # --- SRAM: capacity-proportional static, demand-gated remainder ---
-    pol = cp["sram"]
-    used = np.minimum(1.0, tr.sram_demand / npu.sram_bytes)
-    sram_leak_unused = {"on": 1.0, "sleep": leak_sleep,
-                        "off": leak_off}.get(pol.sram_state, 0.0)
-    static_j["sram"] = static_w["sram"] * float(
-        (durn * (used + (1.0 - used) * sram_leak_unused)).sum())
-    if pol.sram_state != "on":
-        gated["sram"] = float((durn * (1.0 - used)).sum())
-    if pol.sram_state in ("sleep", "off") and pol.mode == "sw" \
-            and tr.n_ops:
-        # one range-setpm pair per demand-CHANGE boundary (matches the
-        # reference engine's prev_used tracking)
-        changes = int(np.count_nonzero(used[1:] != used[:-1]))
-        setpm_by["sram"] = 2.0 * (changes + (1 if used[0] < 1.0 else 0))
-    dynamic_j["sram"] = dyn_w["sram"] * 0.5 * float(
-        (tm["max4"] * cnt).sum())
-
-    # --- other: never gated ---
-    static_j["other"] = static_w["other"] * float(durn.sum())
-    dynamic_j["other"] = dyn_w["other"] * 0.3 * float(durn.sum())
-
-    runtime = float(durn.sum()) + overhead
-    return EnergyReport(
-        workload=wl.name, policy=policy, npu=npu.name,
-        runtime_s=runtime, static_j=static_j, dynamic_j=dynamic_j,
-        setpm_count=sum(setpm_by.values()), wake_events=wakes,
-        gated_s=gated, setpm_by=setpm_by)
 
 
 def _fine_grained_vu_vec(tm: dict, tr: TraceArrays, npu: NPUSpec,
@@ -844,7 +655,7 @@ def _fine_grained_vu_vec(tm: dict, tr: TraceArrays, npu: NPUSpec,
 
 
 # --------------------------------------------------------------------------
-# evaluation — batched sweep plane (stacked traces × npu × policy × knobs)
+# evaluation — the result cube (stacked traces × npu × policy × knobs)
 # --------------------------------------------------------------------------
 
 @dataclass
@@ -954,252 +765,6 @@ class BatchResult:
         return recs
 
 
-def _batch_ctx(st: StackedTrace, npu: NPUSpec) -> dict:
-    """Per-(stacked trace, NPU) arrays shared by every (policy, knob)
-    cell: stacked service times, merged idle-gap structures, and the
-    knob-independent segment sums. Cached on the stack (spec-identity
-    keyed, same convention as ``trace_times``)."""
-    hit = st._derived.get(id(npu))
-    if hit is not None and hit[0] is npu:
-        return hit[1]
-    offs = st.offsets
-    tms = [trace_times(tr, npu) for tr in st.traces]
-
-    def cat(key):
-        if not tms:
-            return np.zeros(0)
-        return np.concatenate([tm[key] for tm in tms])
-
-    tm = {k: cat(k) for k in ("sa", "vu", "hbm", "ici", "dur", "max4",
-                              "frac_on", "frac_w_on", "frac_off")}
-    pm = PowerModel(npu)
-    static_w = pm.static_w
-    dyn_w = pm.dyn_max_w
-    g = npu.gating
-    cnt = st.count
-    dur = tm["dur"]
-    durn = dur * cnt
-    D_seg = segment_sum(durn, offs)
-
-    comp: dict[str, dict] = {}
-    for c in ("sa", "vu", "hbm", "ici"):
-        a = tm[c]
-        active = a > 0
-        gv, gofs = segmented_gaps(active, np.where(active, 0.0, durn), offs)
-        slack = np.where(active, dur - a, 0.0)
-        scnt = slack * cnt
-        acnt = a * cnt
-        comp[c] = {
-            "gap_vals": gv, "gap_offsets": gofs,
-            "S_gap": segment_sum(gv, gofs),
-            "slack": slack, "scnt": scnt, "S_slk": segment_sum(scnt, offs),
-            "acnt": acnt, "AN": segment_sum(acnt, offs),
-        }
-        if c != "sa":  # SA dynamic is work-proportional, not time-based
-            comp[c]["dyn_seg"] = dyn_w[c] * comp[c]["AN"]
-    comp["sa"]["dyn_seg"] = dyn_w["sa"] * segment_sum(
-        st.flops_sa / npu.sa_flops * cnt, offs)
-    # SA spatial-occupancy ingredients (Ideal's occupancy is knob-free)
-    occ_ideal = np.where(st.has_mm, tm["frac_on"], 1.0)
-    comp["sa"]["occ_ideal_AN"] = segment_sum(occ_ideal * comp["sa"]["acnt"],
-                                             offs)
-    # VU fine-grained burst structure (knob-independent parts)
-    vu = comp["vu"]
-    sel = (tm["vu"] > 0) & (vu["slack"] > 0)
-    active_cy = np.maximum(1.0, npu.cycles(tm["vu"]))
-    n_bursts = np.maximum(1.0, active_cy / g.vu_burst_cycles)
-    gap_cy = np.zeros_like(n_bursts)
-    gap_cy[sel] = npu.cycles(vu["slack"][sel]) / n_bursts[sel]
-    inv_gap = np.zeros_like(gap_cy)
-    inv_gap[sel] = 1.0 / gap_cy[sel]
-    psn = static_w["vu"] * vu["slack"] * cnt
-    vu.update(sel=sel, nbn=n_bursts * cnt, gap_cy=gap_cy, inv_gap=inv_gap,
-              psn=psn, PSN_seg=segment_sum(psn, offs))
-    # SRAM capacity model (knob- and policy-independent parts)
-    used = np.minimum(1.0, st.sram_demand / npu.sram_bytes)
-    n = st.n_ops
-    changes = np.zeros(st.n_segments)
-    first = np.zeros(st.n_segments)
-    if n:
-        b = (used[1:] != used[:-1]) & (st.seg_ids[1:] == st.seg_ids[:-1])
-        changes = np.bincount(st.seg_ids[1:][b],
-                              minlength=st.n_segments).astype(np.float64)
-        nonempty = offs[1:] > offs[:-1]
-        first[nonempty] = used[offs[:-1][nonempty]] < 1.0
-    ctx = {
-        "W": st.n_segments, "offsets": offs, "tm": tm, "cnt": cnt,
-        "durn": durn, "D_seg": D_seg, "comp": comp,
-        "static_w": static_w, "dyn_w": dyn_w, "gating": g,
-        "freq": npu.freq_hz, "has_mm": st.has_mm,
-        "sram_used": used,
-        "sram_U_seg": segment_sum(durn * used, offs),
-        "sram_GU_seg": segment_sum(durn * (1.0 - used), offs),
-        "sram_setpm_seg": 2.0 * (changes + first),
-        "sram_dyn_seg": dyn_w["sram"] * 0.5 * segment_sum(tm["max4"] * cnt,
-                                                          offs),
-    }
-    st._derived[id(npu)] = (npu, ctx)
-    return ctx
-
-
-def _comp_cell(ctx: dict, c: str, pol: _CompPolicy, kp: dict) -> dict:
-    """Batched per-component evaluation of one ``_CompPolicy`` over the
-    knob axis: (W, K) arrays for static energy, exposed-wake overhead,
-    wake events, setpm count, and gated seconds.
-
-    The gated-idle energy model is piecewise linear in the gap length
-    with knob-dependent thresholds, so instead of materializing per-gap
-    energies per knob, the cell reduces the masked gap sums/counts per
-    segment and assembles every quantity in closed form — identical
-    values to ``_gated_idle_energy_vec`` summed per workload.
-    """
-    cc = ctx["comp"][c]
-    offs = ctx["offsets"]
-    W, K = ctx["W"], kp["K"]
-    p = ctx["static_w"][c]
-    g = ctx["gating"]
-    leak = kp["leak_logic"]
-    if c == "hbm":
-        # HBM auto-refresh floor (paper §6.5)
-        leak = np.maximum(leak, g.leak_hbm_refresh)
-    bet = g.bet.get(pol.delay_key, 0) * kp["dscale"] / ctx["freq"]
-    delay = g.on_off_delay.get(pol.delay_key, 0) * kp["dscale"] / ctx["freq"]
-    window = bet * g.detection_window_frac * kp["wscale"]
-
-    static = np.zeros((W, K))
-    overhead = np.zeros((W, K))
-    wakes = np.zeros((W, K))
-    setpm = np.zeros((W, K))
-    gated = np.zeros((W, K))
-    S = cc["S_gap"][:, None]
-
-    # --- merged cross-op idle gaps (each closed once, not per instance) ---
-    if pol.mode == "none":
-        static += p * S
-    elif pol.mode == "ideal":
-        gated += S
-    elif pol.mode == "hw":
-        gv = cc["gap_vals"]
-        mask = gv[:, None] > window[None, :]
-        GM = segment_sum(np.where(mask, gv[:, None], 0.0),
-                         cc["gap_offsets"])
-        C = segment_sum(mask.astype(np.float64), cc["gap_offsets"])
-        static += p * (S - GM) + (p * window) * C \
-            + (leak * p) * (GM - window * C) + (p * delay) * C
-        overhead += delay * C
-        wakes += C
-        gated += GM - window * C
-    else:  # sw
-        thresh = np.maximum(bet, 2.0 * delay)
-        gv = cc["gap_vals"]
-        mask = (gv[:, None] >= thresh[None, :]) & (gv > 0)[:, None]
-        GM = segment_sum(np.where(mask, gv[:, None], 0.0),
-                         cc["gap_offsets"])
-        C = segment_sum(mask.astype(np.float64), cc["gap_offsets"])
-        static += p * (S - GM) + (leak * p) * (GM - 2.0 * delay * C) \
-            + (p * 2.0 * delay) * C
-        wakes += C
-        setpm += 2.0 * C
-        gated += GM - 2.0 * delay * C
-
-    # --- active-portion static (SA: PE-occupancy weighted) ---
-    if c == "sa" and pol.spatial_sa:
-        if pol.mode == "ideal":
-            static += p * cc["occ_ideal_AN"][:, None]
-        else:
-            tm = ctx["tm"]
-            occ = tm["frac_on"][:, None] \
-                + g.leak_pe_weight_on * tm["frac_w_on"][:, None] \
-                + kp["leak_logic"][None, :] * tm["frac_off"][:, None]
-            occ = np.where(ctx["has_mm"][:, None], occ, 1.0)
-            static += p * segment_sum(occ * cc["acnt"][:, None], offs)
-    else:
-        static += p * cc["AN"][:, None]
-
-    # --- within-op slack (per executed instance) ---
-    if c == "vu":
-        _vu_fine_cell(ctx, pol, kp, leak, static, overhead, wakes, setpm,
-                      gated)
-    else:
-        Ss = cc["S_slk"][:, None]
-        if pol.mode == "none":
-            static += p * Ss
-        elif pol.mode == "ideal":
-            gated += Ss
-        else:
-            slack = cc["slack"]
-            if pol.mode == "hw":
-                mask = slack[:, None] > window[None, :]
-                lo, hi = window, delay
-            else:  # sw
-                thresh = np.maximum(bet, 2.0 * delay)
-                mask = (slack[:, None] >= thresh[None, :]) \
-                    & (slack > 0)[:, None]
-                lo = hi = 2.0 * delay
-            SM = segment_sum(np.where(mask, cc["scnt"][:, None], 0.0), offs)
-            CM = segment_sum(np.where(mask, ctx["cnt"][:, None], 0.0), offs)
-            if pol.mode == "hw":
-                static += p * (Ss - SM) + (p * lo) * CM \
-                    + (leak * p) * (SM - lo * CM) + (p * hi) * CM
-                overhead += hi * CM
-            else:
-                static += p * (Ss - SM) + (leak * p) * (SM - lo * CM) \
-                    + (p * lo) * CM
-                setpm += 2.0 * CM
-            wakes += CM
-            gated += SM - lo * CM
-
-    if c in ("hbm", "ici"):
-        # wake overlapped with the long DMA issue latency half the time
-        overhead *= 0.5
-    return {"static": static, "overhead": overhead, "wakes": wakes,
-            "setpm": setpm, "gated": gated}
-
-
-def _vu_fine_cell(ctx, pol, kp, leak, static, overhead, wakes, setpm,
-                  gated):
-    """Knob-axis-batched ``_fine_grained_vu_vec``: per-burst VU slack
-    inside mixed ops (paper Fig 15). Mutates the (W, K) accumulators."""
-    cc = ctx["comp"]["vu"]
-    offs = ctx["offsets"]
-    g = ctx["gating"]
-    if pol.mode == "none":
-        static += cc["PSN_seg"][:, None]
-        return
-    if pol.mode == "ideal":
-        gated += cc["S_slk"][:, None]
-        return
-    bet_cy = g.bet["vu"] * kp["dscale"]
-    delay_cy = g.on_off_delay["vu"] * kp["dscale"]
-    gap_cy = cc["gap_cy"]
-    psn = cc["psn"][:, None]
-    if pol.mode == "hw":
-        window_cy = bet_cy * g.detection_window_frac * kp["wscale"]
-        gm = gap_cy[:, None] > bet_cy[None, :]
-        gf = np.maximum(0.0, 1.0 - window_cy[None, :]
-                        * cc["inv_gap"][:, None])
-        e = np.where(gm, psn * ((1.0 - gf) + leak * gf), psn)
-        static += segment_sum(e, offs)
-        gated += segment_sum(np.where(gm, cc["scnt"][:, None] * gf, 0.0),
-                             offs)
-        NB = segment_sum(np.where(gm, cc["nbn"][:, None], 0.0), offs)
-        # exposed wake per burst: Base/HW hardware cannot pre-wake
-        overhead += delay_cy / ctx["freq"] * NB
-        wakes += NB
-        return
-    # sw
-    gm = cc["sel"][:, None] & (
-        gap_cy[:, None] >= np.maximum(bet_cy, 2.0 * delay_cy)[None, :])
-    trans = 2.0 * delay_cy[None, :] * cc["inv_gap"][:, None]
-    e = np.where(gm, psn * (trans + leak * (1.0 - trans)), psn)
-    static += segment_sum(e, offs)
-    gated += segment_sum(
-        np.where(gm, cc["scnt"][:, None] * (1.0 - trans), 0.0), offs)
-    NB = segment_sum(np.where(gm, cc["nbn"][:, None], 0.0), offs)
-    setpm += 2.0 * NB
-    wakes += NB
-
-
 # --------------------------------------------------------------------------
 # evaluation — backend-neutral sweep kernel (numpy or one jitted jax program)
 # --------------------------------------------------------------------------
@@ -1242,7 +807,7 @@ def _sweep_kernel(data, knobs, policies, bk, wl_axis=None, knob_axis=None):
     program: the SA width ``saw`` enters as a traced scalar, which is
     what turns ``sa_width`` into a real knob axis (ISSUE 5). Distinct
     ``_CompPolicy`` cells are computed once and shared across policies
-    (same memoization as the numpy path, applied at trace time).
+    (memoized at trace time).
 
     The knob axis is factored: the O(n_ops)-sized work — occupancy,
     service times, gap merges, masked threshold merges — depends only
@@ -1458,7 +1023,7 @@ def _sweep_kernel(data, knobs, policies, bk, wl_axis=None, knob_axis=None):
     leak_logic = knobs["leak_logic"][:, None]
 
     def cell(c, pol):
-        """(K, W) closed-form assembly of one ``_comp_cell``."""
+        """(K, W) closed-form assembly of one component-policy cell."""
         p = scal[f"static_w_{c}"]
         leak = leak_logic
         if c == "hbm":
@@ -1780,7 +1345,7 @@ def _backend_data(st: StackedTrace, npu: NPUSpec, bk) \
     """``_host_columns`` transferred to the backend once, as one float64
     and one int64 slab (``put_slabs``: ``(layout, slabs)``), and cached
     on the stack (spec-identity keyed, same convention as
-    ``_batch_ctx``)."""
+    ``trace_times``)."""
     key = ("backend_data", bk.name, id(npu))
     hit = st._derived.get(key)
     if hit is not None and hit[0] is npu:
@@ -2056,22 +1621,23 @@ def _validate_knob_grid(knob_grid) -> None:
 def evaluate_batch(workloads, npus=("NPU-D",), policies=POLICIES,
                    knob_grid=None, *, backend: Optional[str] = None,
                    jax_mesh=None) -> BatchResult:
-    """Batched ``evaluate`` over the full design-space cross product.
+    """Every (workload × npu × policy × knob) cell through the sweep
+    kernel (``_sweep_kernel``), one kernel call per NPU.
 
-    The workloads are stacked into one ragged super-trace; per-(trace,
-    NPU) service times and idle-gap structures are computed once and
-    reused across every (policy, knob) cell; component results are
-    memoized per distinct ``_CompPolicy`` (ReGate-HW and ReGate-Full
-    share the SA cell, ReGate-Base and ReGate-HW share VU/HBM/ICI/SRAM,
-    …); the knob axis rides along as a trailing array dimension.
-    Cell-for-cell equivalent to looping ``evaluate`` to ≤1e-9 relative.
+    The workloads are stacked into one ragged super-trace; service
+    times, SA occupancy and idle-gap merges run once per unique SA width
+    and (width, delay, window) triple inside the kernel, and component
+    results are memoized per distinct ``_CompPolicy`` (ReGate-HW and
+    ReGate-Full share the SA cell, ReGate-Base and ReGate-HW share
+    VU/HBM/ICI/SRAM, …). Cell-for-cell equivalent to
+    ``evaluate_reference`` to ≤1e-9 relative.
 
-    ``backend`` selects the array substrate: ``"numpy"`` (default — the
-    eager production oracle) or ``"jax"`` (one jitted program per stack
-    shape, float64, reused across NPU generations; ≤1e-9 equivalent to
-    the numpy path record-for-record). ``None`` resolves to the session
-    default (``repro.core.backend.set_default_backend``). ``jax_mesh``
-    scales the jax path across devices (``parallel.jax_compat``; e.g.
+    ``backend`` selects the array substrate the kernel runs on:
+    ``"numpy"`` (default — the kernel eagerly on the host) or ``"jax"``
+    (one jitted program per stack shape, float64, reused across NPU
+    generations). ``None`` resolves to the session default
+    (``repro.core.backend.set_default_backend``). ``jax_mesh`` scales
+    the jax path across devices (``parallel.jax_compat``; e.g.
     ``jax_compat.sweep_mesh``): a pure ``("wl",)`` mesh shards the
     stacked per-op arrays under GSPMD, while a mesh with a ``"knob"``
     axis — optionally crossed with ``"wl"`` — runs the explicit
@@ -2095,108 +1661,28 @@ def evaluate_batch(workloads, npus=("NPU-D",), policies=POLICIES,
     if jax_mesh is None and backend != "numpy":
         from repro.core import session
         jax_mesh = session.resolve("jax_mesh")
-    if backend != "numpy" or jax_mesh is not None:
-        if jax_mesh is not None and backend == "numpy":
-            raise ValueError("jax_mesh requires backend='jax'")
-        bk = get_backend(backend)
-        with bk.span("regate.evaluate_batch"):
-            return _evaluate_batch_backend(workloads, npu_specs, policies,
-                                           knob_grid, bk, mesh=jax_mesh)
-    st = stack_traces(workloads)
-    W, A, P, K = len(workloads), len(npu_specs), len(policies), \
-        len(knob_grid)
-    shape = (W, A, P, K)
-    runtime = np.zeros(shape)
-    static_j = {c: np.zeros(shape) for c in COMPONENTS}
-    dynamic_j = {c: np.zeros(shape) for c in COMPONENTS}
-    wake_events = {c: np.zeros(shape) for c in COMPONENTS}
-    gated_s = {c: np.zeros(shape) for c in COMPONENTS}
-    setpm_by = {c: np.zeros(shape) for c in COMPONENTS}
+    if jax_mesh is not None and backend == "numpy":
+        raise ValueError("jax_mesh requires backend='jax'")
+    bk = get_backend(backend)
+    with bk.span("regate.evaluate_batch"):
+        return _evaluate_batch_backend(workloads, npu_specs, policies,
+                                       knob_grid, bk, mesh=jax_mesh)
 
-    for ai, base_npu in enumerate(npu_specs):
-        # group the knob grid by effective SA width: each group runs on
-        # a memoized width-variant spec (the scalar engines' oracle
-        # semantics), scattering its columns back into the knob axis
-        saw_of = [k.sa_width if k.sa_width is not None
-                  else base_npu.sa_width for k in knob_grid]
-        for saw in dict.fromkeys(saw_of):
-            idx = np.flatnonzero(np.array(saw_of) == saw)
-            sub_grid = [knob_grid[i] for i in idx]
-            npu = with_sa_width(base_npu, saw)
-            ctx = _batch_ctx(st, npu)
-            g = ctx["gating"]
-            kp = {
-                "K": len(sub_grid),
-                "dscale": np.array([k.delay_scale for k in sub_grid]),
-                "wscale": np.array([k.window_scale for k in sub_grid]),
-                "leak_logic": np.array(
-                    [k.leak_off_logic if k.leak_off_logic is not None
-                     else g.leak_off_logic for k in sub_grid]),
-                "leak_sleep": np.array(
-                    [k.leak_sram_sleep if k.leak_sram_sleep is not None
-                     else g.leak_sram_sleep for k in sub_grid]),
-                "leak_off": np.array(
-                    [k.leak_sram_off if k.leak_sram_off is not None
-                     else g.leak_sram_off for k in sub_grid]),
-            }
-            cell_cache: dict = {}
-            for pi, policy in enumerate(policies):
-                cp = _component_policies(policy)
-                ov_total = np.zeros((W, len(sub_grid)))
-                for c in ("sa", "vu", "hbm", "ici"):
-                    key = (c, cp[c])
-                    cell = cell_cache.get(key)
-                    if cell is None:
-                        cell = _comp_cell(ctx, c, cp[c], kp)
-                        cell_cache[key] = cell
-                    static_j[c][:, ai, pi, idx] = cell["static"]
-                    wake_events[c][:, ai, pi, idx] = cell["wakes"]
-                    setpm_by[c][:, ai, pi, idx] = cell["setpm"]
-                    gated_s[c][:, ai, pi, idx] = cell["gated"]
-                    dynamic_j[c][:, ai, pi, idx] = \
-                        ctx["comp"][c]["dyn_seg"][:, None]
-                    ov_total += cell["overhead"]
 
-                # --- SRAM: capacity-proportional static, gated rest ---
-                pol = cp["sram"]
-                lk = {"on": np.ones(len(sub_grid)),
-                      "sleep": kp["leak_sleep"],
-                      "off": kp["leak_off"]}.get(pol.sram_state,
-                                                 np.zeros(len(sub_grid)))
-                static_j["sram"][:, ai, pi, idx] = \
-                    ctx["static_w"]["sram"] * (
-                        ctx["sram_U_seg"][:, None]
-                        + lk[None, :] * ctx["sram_GU_seg"][:, None])
-                if pol.sram_state != "on":
-                    gated_s["sram"][:, ai, pi, idx] = \
-                        ctx["sram_GU_seg"][:, None]
-                if pol.sram_state in ("sleep", "off") \
-                        and pol.mode == "sw":
-                    setpm_by["sram"][:, ai, pi, idx] = \
-                        ctx["sram_setpm_seg"][:, None]
-                dynamic_j["sram"][:, ai, pi, idx] = \
-                    ctx["sram_dyn_seg"][:, None]
-
-                # --- other: never gated ---
-                static_j["other"][:, ai, pi, idx] = \
-                    (ctx["static_w"]["other"] * ctx["D_seg"])[:, None]
-                dynamic_j["other"][:, ai, pi, idx] = \
-                    (ctx["dyn_w"]["other"] * 0.3 * ctx["D_seg"])[:, None]
-
-                runtime[:, ai, pi, idx] = ctx["D_seg"][:, None] + ov_total
-
-    return BatchResult(
-        workloads=tuple(st.names), npus=npu_specs, policies=policies,
-        knob_grid=knob_grid, runtime_s=runtime, static_j=static_j,
-        dynamic_j=dynamic_j, wake_events=wake_events, gated_s=gated_s,
-        setpm_by=setpm_by)
+def evaluate(wl: Workload, npu: NPUSpec | str = "NPU-D",
+             policy: str = "ReGate-Full",
+             knobs: PolicyKnobs = PolicyKnobs()) -> EnergyReport:
+    """One cell of ``evaluate_batch`` on the host (numpy, whatever the
+    session's backend); semantics identical to ``evaluate_reference``."""
+    return evaluate_batch([wl], (npu,), (policy,), (knobs,),
+                          backend="numpy").report(0, 0, 0, 0)
 
 
 def evaluate_all(wl: Workload, npu="NPU-D",
                  knobs: PolicyKnobs = PolicyKnobs()) \
         -> dict[str, EnergyReport]:
-    """All five policies for one workload — a thin wrapper over the
-    batched plane (one stacked pass instead of five engine calls)."""
+    """All five policies for one workload — one ``evaluate_batch``
+    call instead of five ``evaluate`` calls."""
     res = evaluate_batch(wl, (npu,), POLICIES, (knobs,))
     return {p: res.report(0, 0, pi, 0) for pi, p in enumerate(POLICIES)}
 

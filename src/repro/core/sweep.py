@@ -9,16 +9,14 @@ stacked into one ragged super-trace, per-(trace, npu) service times are
 reused across the policy/knob axes, and the records fall out of a
 handful of segmented array passes — no per-cell Python round-trips.
 
-``sweep_reference`` keeps the original one-``evaluate``-call-per-cell
-loop as the oracle; ``benchmarks/perf_sweep.py`` gates the batched path
-≥10× faster with record-for-record ≤1e-9 relative equivalence.
+``sweep_reference`` is the oracle: one ``evaluate_reference`` call (the
+scalar per-op loop) per cell; ``tests/test_sweep_batch.py`` holds
+``sweep`` to it record-for-record at ≤1e-9 relative.
 
 ``sweep_grid`` crosses the §6.5 sensitivity axes (wake-delay scale,
 gated leakage ratios, SRAM sleep/off leakage, SA width) into a single
 fine-grid ``evaluate_batch`` call; with ``backend="jax"`` the grid runs
-as one jitted float64 program reused across NPU generations
-(``benchmarks/perf_sweep_jax.py`` gates ≥3× over the numpy batched path
-on a ≥100k-cell grid, record-for-record ≤1e-9).
+as one jitted float64 program reused across NPU generations.
 
 Records are emitted in deterministic order: workload-major, then NPU,
 then policy, then knob index (both paths, byte-identical ordering).
@@ -32,8 +30,8 @@ import numpy as np
 from repro.core.hw import NPUSpec, get_npu
 from repro.core.opgen import Workload, compile_trace
 from repro.core.policies import (POLICIES, BatchResult, EnergyReport,
-                                 KnobGrid, PolicyKnobs, evaluate,
-                                 evaluate_batch, knob_columns)
+                                 KnobGrid, PolicyKnobs, evaluate_batch,
+                                 evaluate_reference, knob_columns)
 from repro.core.guard import (GuardPolicy,  # noqa: F401  (re-export)
                               GuardReport)
 from repro.core.power import COMPONENTS
@@ -330,11 +328,11 @@ def sweep_reference(workloads: Sequence[Workload] | Workload,
                     policies: Iterable[str] = POLICIES,
                     knob_grid: Optional[Sequence[PolicyKnobs]] = None) \
         -> list[dict]:
-    """The original loop sweep — one ``evaluate`` round-trip per cell.
+    """The loop sweep — one ``evaluate_reference`` call per cell.
 
-    Kept as the oracle for the batched path: same records, same
-    deterministic ordering; ``tests/test_sweep_batch.py`` holds the two
-    to ≤1e-9 relative on every record field.
+    The oracle for ``sweep``, independent of the sweep kernel: same
+    records, same deterministic ordering; ``tests/test_sweep_batch.py``
+    holds the two to ≤1e-9 relative on every record field.
     """
     if isinstance(workloads, Workload):
         workloads = [workloads]
@@ -343,11 +341,11 @@ def sweep_reference(workloads: Sequence[Workload] | Workload,
     npu_specs = [get_npu(n) if isinstance(n, str) else n for n in npus]
     records: list[dict] = []
     for wl in workloads:
-        compile_trace(wl)  # compile once up front (cached by identity)
+        compile_trace(wl)  # rejects a malformed op stream, as sweep does
         for npu in npu_specs:
             for policy in policies:
                 for ki, knobs in enumerate(knob_grid):
-                    rep = evaluate(wl, npu, policy, knobs)
+                    rep = evaluate_reference(wl, npu, policy, knobs)
                     records.append(_flatten(rep, knobs, ki, npu))
     return records
 

@@ -1,11 +1,13 @@
-"""JAX array backend vs the numpy batched plane (ISSUE 4).
+"""JAX array backend vs the sweep kernel's numpy instantiation.
 
-The jax backend must reproduce ``evaluate_batch`` (numpy) — and through
-it ``sweep_reference`` — record-for-record to ≤1e-9 relative on every
-numeric field: the full acceptance grid (suite × 5 NPUs × 5 policies ×
-4 knobs), randomized ragged stacks with empty and single-op workloads
-mixed in, knob grids of size 1, and the ``sweep_grid`` fine-knob cross
-product with SA-width variants. Also: the x64 requirement raises a
+The jax backend must reproduce ``evaluate_batch`` on numpy (the same
+backend-neutral kernel run eagerly on the host) — and the oracle
+``sweep_reference`` (one scalar ``evaluate_reference`` per cell) —
+record-for-record to ≤1e-9 relative on every numeric field: the full
+acceptance grid (suite × 5 NPUs × 5 policies × 4 knobs), randomized
+ragged stacks with empty and single-op workloads mixed in, knob grids
+of size 1, and the ``sweep_grid`` fine-knob cross product with SA-width
+variants. Also: the x64 requirement raises a
 clear error instead of silently degrading to f32, and sharding the
 stacked workload axis over a ``jax_compat`` mesh changes nothing.
 """
@@ -19,7 +21,7 @@ from repro.core.hw import NPUS, get_npu  # noqa: E402
 from repro.core.opgen import (Op, Workload, paper_suite,  # noqa: E402
                               segmented_gaps)
 from repro.core.policies import (POLICIES, PolicyKnobs,  # noqa: E402
-                                 evaluate, evaluate_batch)
+                                 evaluate_batch, evaluate_reference)
 from repro.core.sweep import (sweep, sweep_grid,  # noqa: E402
                               sweep_reference)
 
@@ -43,7 +45,7 @@ KNOB_GRID = [
 
 def test_full_grid_matches_numpy_batched():
     """The ISSUE-4 acceptance grid, record-for-record ≤1e-9 with
-    byte-identical ordering against the numpy batched path."""
+    byte-identical ordering against the kernel on numpy."""
     suite = paper_suite()
     npus = tuple(NPUS)
     ref = sweep(suite, npus, POLICIES, KNOB_GRID, backend="numpy")
@@ -55,8 +57,9 @@ def test_full_grid_matches_numpy_batched():
 
 
 def test_matches_sweep_reference_loop_oracle():
-    """Transitively through the numpy plane is not enough: hold the jax
-    backend directly to the original one-evaluate-per-cell loop."""
+    """Agreement with the kernel on numpy is not enough: hold the jax
+    backend directly to the oracle, one ``evaluate_reference`` per
+    cell."""
     wls = paper_suite()[:3]
     grid = [PolicyKnobs(), PolicyKnobs(delay_scale=4.0)]
     ref = sweep_reference(wls, ("NPU-B", "NPU-E"), POLICIES, grid)
@@ -93,8 +96,9 @@ def _random_workload(rng: np.random.Generator, i: int,
 
 def test_randomized_ragged_stack_property():
     """Random ragged stack with empty and single-op workloads mixed in:
-    the jax backend must match per-workload ``evaluate`` cell-for-cell
-    (and the empty segments must come back as exact zeros)."""
+    the jax backend must match the per-workload scalar oracle
+    ``evaluate_reference`` cell-for-cell (and the empty segments must
+    come back as exact zeros)."""
     rng = np.random.default_rng(11)
     sizes = [0, 1, int(rng.integers(2, 30)), 0, 1,
              int(rng.integers(2, 30)), int(rng.integers(2, 30)), 0]
@@ -107,7 +111,7 @@ def test_randomized_ragged_stack_property():
         for ai, npu in enumerate(npus):
             for pi, policy in enumerate(POLICIES):
                 for ki, knobs in enumerate(grid):
-                    want = evaluate(wl, npu, policy, knobs)
+                    want = evaluate_reference(wl, npu, policy, knobs)
                     got = res.report(wi, ai, pi, ki)
                     _assert_reports_match(got, want,
                                           (wl.name, npu, policy, ki))
@@ -162,9 +166,9 @@ def test_sweep_grid_cross_product_equivalence():
 def test_sweep_grid_sa_width_axis():
     """``sa_width`` is a real knob axis (ISSUE 5): the NPU axis stays
     untouched, records carry the width in their ``sa_width`` column,
-    the traced-saw jax kernel matches a direct evaluation on a
-    width-replaced spec, and a non-native width genuinely changes the
-    SA numbers."""
+    the traced-saw jax kernel matches the scalar oracle
+    ``evaluate_reference`` both with the knob and on a width-replaced
+    spec, and a non-native width genuinely changes the SA numbers."""
     wl = paper_suite()[4]  # prefill, SA-heavy
     res = sweep_grid(wl, ("NPU-D",), ("NoPG", "ReGate-HW"),
                      sa_width=(None, 256), backend="jax",
@@ -178,15 +182,16 @@ def test_sweep_grid_sa_width_axis():
     wide = [r for r in recs if r["sa_width"] == 256
             and r["policy"] == "ReGate-HW"][0]
     assert native["runtime_s"] != wide["runtime_s"]
-    # per-width cells equal a direct scalar evaluation with the knob
-    want = evaluate(wl, "NPU-D", "ReGate-HW", PolicyKnobs(sa_width=256))
+    # per-width cells equal the scalar oracle with the knob
+    want = evaluate_reference(wl, "NPU-D", "ReGate-HW",
+                              PolicyKnobs(sa_width=256))
     assert _rel(wide["total_j"], want.total_j) <= RTOL
-    # ... and a direct evaluation on the width-replaced spec (wider SA
+    # ... and the oracle on the width-replaced spec (wider SA
     # also means higher peak FLOP/s — the derived sa_flops moved too)
     from repro.core.hw import with_sa_width
     spec = with_sa_width(get_npu("NPU-D"), 256)
     assert spec.sa_flops > get_npu("NPU-D").sa_flops
-    want2 = evaluate(wl, spec, "ReGate-HW")
+    want2 = evaluate_reference(wl, spec, "ReGate-HW")
     assert _rel(wide["total_j"], want2.total_j) <= RTOL
 
 
@@ -228,9 +233,9 @@ def test_jax_mesh_requires_jax_backend():
 def test_shard_map_mesh_matches_numpy(axes):
     """A mesh with a ``"knob"`` axis selects the explicit shard_map
     program (op columns psum-completed over ``wl``, pairs + knobs
-    sharded over ``knob``); every topology must match the numpy oracle
-    record-for-record — including knob/pair counts that do not divide
-    the axis size (the padding path)."""
+    sharded over ``knob``); every topology must match the kernel on
+    numpy record-for-record — including knob/pair counts that do not
+    divide the axis size (the padding path)."""
     from repro.core.sweep import knob_product
     from repro.parallel import jax_compat
     n_dev = len(jax.devices())

@@ -197,7 +197,7 @@ def test_quarantine_patches_to_oracle():
     assert sorted(e["cell"] for e in q) == [[0, 0, 0, 0], [1, 0, 1, 1]]
     assert q[0]["fields"] == ["runtime_s"] and q[0]["step"] == 3
     assert "non-finite runtime_s" in q[0]["reason"]
-    assert "numpy oracle" in q[0]["reason"]
+    assert "numpy backend" in q[0]["reason"]
     assert q[1]["fields"] == ["static_j[sa]"]
     assert [e["kind"] for e in evs][-1] == "oracle_recheck"
     assert evs[-1]["n_quarantined"] == 2

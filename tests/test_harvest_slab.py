@@ -6,7 +6,7 @@ single copy back into views (``policies._unpack``). Pinned here: a jax
 ``evaluate_batch`` makes exactly one ``to_numpy`` per NPU on every path
 (single device, the GSPMD ``("wl",)`` mesh, the ``shard_map`` meshes,
 and the numpy instantiation of the same kernel); its records match the
-numpy oracle to ≤1e-9; and the slab reads back every leaf of the
+kernel on numpy to ≤1e-9; and the slab reads back every leaf of the
 unpacked kernel bit for bit.
 """
 import os

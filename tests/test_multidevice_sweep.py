@@ -3,7 +3,7 @@ device count must be fixed before jax initializes). The ISSUE-5
 equivalence contract on a real multi-device topology: the shard_map
 program — op columns sharded over ``"wl"`` with in-kernel psums, unique
 (saw, delay) pairs + knob grid sharded over ``"knob"`` — must match the
-numpy oracle record-for-record ≤1e-9 on every mesh shape, including
+kernel on numpy record-for-record ≤1e-9 on every mesh shape, including
 axis sizes that do not divide the op/pair/knob counts (padding)."""
 import subprocess
 import sys

@@ -3,8 +3,8 @@
 Covers the determinism contract (same Generator seed -> bit-identical
 perturbed traces), the conservation invariants of each transform, the
 severity axis (0 = exact identity), the perturbed-stack sweep
-equivalence (numpy batched vs scalar oracle; jax vs numpy when jax is
-present), and the >= 200-program EventTimeline-vs-VLIWTimeline
+equivalence (the kernel on numpy vs scalar oracle; jax vs numpy when
+jax is present), and the >= 200-program EventTimeline-vs-VLIWTimeline
 differential fuzz harness.
 """
 import dataclasses
@@ -20,7 +20,8 @@ from repro.core.perturb import (FUZZ_KW, BurstCompression, ClockJitter,
                                 Straggler, adversarial_events,
                                 differential_fuzz, perturb_suite,
                                 perturb_workload, severity_plan)
-from repro.core.policies import PolicyKnobs, evaluate, evaluate_batch
+from repro.core.policies import (PolicyKnobs, evaluate_batch,
+                                 evaluate_reference)
 
 from _sweep_equiv import rel
 
@@ -146,7 +147,7 @@ def test_perturbed_stack_numpy_matches_scalar_oracle():
     for wi, wl in enumerate(pert):
         for pi, pol in enumerate(pols):
             for ki, kn in enumerate(grid):
-                ref = evaluate(wl, "NPU-D", pol, kn)
+                ref = evaluate_reference(wl, "NPU-D", pol, kn)
                 got = res.report(wi, 0, pi, ki)
                 assert rel(ref.runtime_s, got.runtime_s) <= 1e-9
                 assert rel(ref.total_j, got.total_j) <= 1e-9

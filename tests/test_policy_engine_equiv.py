@@ -1,10 +1,10 @@
-"""Equivalence of the columnar policy engine against the scalar oracle.
+"""Equivalence of the policy engine against the scalar oracle.
 
-The vectorized ``evaluate`` must reproduce ``evaluate_reference`` to
-<=1e-9 relative on every EnergyReport field, across the full paper suite
-x all 5 policies x all NPU generations (plus knob overrides), and the
-batched SA-gating math must match its scalar originals on randomized
-shapes.
+``evaluate`` — one cell of the sweep kernel's numpy instantiation —
+must reproduce the oracle ``evaluate_reference`` to <=1e-9 relative on
+every EnergyReport field, across the full paper suite x all 5 policies
+x all NPU generations (plus knob overrides), and the batched SA-gating
+math must match its scalar originals on randomized shapes.
 """
 import math
 

@@ -1,12 +1,14 @@
 """Batched sweep plane vs the loop oracle.
 
 The batched ``sweep`` (one ``evaluate_batch`` over the stacked
-super-trace) must reproduce ``sweep_reference`` record-for-record to
-≤1e-9 relative on every numeric field with identical deterministic
-ordering, across the paper suite × all 5 NPU generations × all policies
-× a multi-point knob grid. A randomized ragged-stacking property test
-checks that segment ids never leak idle-gap merging across workload
-boundaries (the per-workload engine is the oracle).
+super-trace: the sweep kernel's numpy instantiation) must reproduce
+``sweep_reference`` — one scalar ``evaluate_reference`` call per cell —
+record-for-record to ≤1e-9 relative on every numeric field with
+identical deterministic ordering, across the paper suite × all 5 NPU
+generations × all policies × a multi-point knob grid. A randomized
+ragged-stacking property test checks that segment ids never leak
+idle-gap merging across workload boundaries (the per-workload
+``evaluate_reference`` is the oracle).
 """
 import math
 
@@ -15,9 +17,10 @@ import pytest
 
 from repro.core.hw import NPUS, get_npu
 from repro.core.opgen import (Op, Workload, compile_trace, paper_suite,
-                              segment_sum, segmented_gaps, stack_traces)
+                              segmented_gaps, stack_traces)
 from repro.core.policies import (POLICIES, PolicyKnobs, evaluate,
-                                 evaluate_all, evaluate_batch)
+                                 evaluate_all, evaluate_batch,
+                                 evaluate_reference)
 from repro.core.power import COMPONENTS
 from repro.core.sweep import sweep, sweep_reference, with_savings
 
@@ -103,8 +106,8 @@ def _random_workload(rng: np.random.Generator, i: int) -> Workload:
 
 def test_ragged_stacking_no_gap_leakage():
     """evaluate_batch over a random ragged stack must equal per-workload
-    evaluate: if gap merging leaked across segment boundaries, the
-    hw/sw gated-idle energies would differ."""
+    evaluate_reference: if gap merging leaked across segment boundaries,
+    the hw/sw gated-idle energies would differ."""
     rng = np.random.default_rng(SEED_STACKING)
     wls = [_random_workload(rng, i) for i in range(12)]
     grid = [PolicyKnobs(), PolicyKnobs(delay_scale=3.0),
@@ -115,7 +118,7 @@ def test_ragged_stacking_no_gap_leakage():
         for ai, npu in enumerate(npus):
             for pi, policy in enumerate(POLICIES):
                 for ki, knobs in enumerate(grid):
-                    want = evaluate(wl, npu, policy, knobs)
+                    want = evaluate_reference(wl, npu, policy, knobs)
                     got = res.report(wi, ai, pi, ki)
                     ctx = (wl.name, npu, policy, ki)
                     assert _rel(got.runtime_s, want.runtime_s) <= RTOL, ctx
@@ -137,8 +140,8 @@ def test_empty_trace_in_ragged_stack():
     """Regression (ISSUE 4): zero-op workloads mixed into a randomized
     ragged stack — leading, trailing, and consecutive empty segments —
     must yield exactly-zero records without NaNs and without shifting
-    any neighbour's segment alignment (per-workload ``evaluate`` is the
-    oracle)."""
+    any neighbour's segment alignment (per-workload
+    ``evaluate_reference`` is the oracle)."""
     rng = np.random.default_rng(SEED_EMPTY)
     empty = Workload("empty", "prefill", ())
     wls = [empty, _random_workload(rng, 1), empty,
@@ -150,7 +153,7 @@ def test_empty_trace_in_ragged_stack():
         for ai, npu in enumerate(("NPU-A", "NPU-E")):
             for pi, policy in enumerate(POLICIES):
                 for ki, knobs in enumerate(grid):
-                    want = evaluate(wl, npu, policy, knobs)
+                    want = evaluate_reference(wl, npu, policy, knobs)
                     got = res.report(wi, ai, pi, ki)
                     ctx = (wl.name, npu, policy, ki)
                     assert _rel(got.runtime_s, want.runtime_s) <= RTOL, ctx
@@ -234,12 +237,19 @@ def test_stack_traces_segments_and_cache():
 
 
 def test_segment_sum_handles_empty_segments():
-    arr = np.arange(6, dtype=np.float64).reshape(6, 1)
-    offsets = np.array([0, 2, 2, 5, 6])
-    out = segment_sum(arr, offsets)
-    assert out.shape == (4, 1)
-    assert out[:, 0].tolist() == [1.0, 0.0, 9.0, 5.0]
-    assert segment_sum(np.zeros((0, 2)), np.array([0, 0, 0])).shape == (2, 2)
+    """The kernel's host ``segment_sum`` (bincount over segment ids):
+    empty segments, trailing empty segments and an empty input all sum
+    to exact zeros of length ``num_segments``."""
+    from repro.core.backend import NumpyBackend
+    data = np.arange(6, dtype=np.float64)
+    seg_ids = np.array([0, 0, 2, 2, 2, 3])
+    out = NumpyBackend.segment_sum(data, seg_ids, 4)
+    assert out.dtype == np.float64
+    assert out.tolist() == [1.0, 0.0, 9.0, 5.0]
+    assert NumpyBackend.segment_sum(data, seg_ids, 6).tolist() == \
+        [1.0, 0.0, 9.0, 5.0, 0.0, 0.0]
+    empty = NumpyBackend.segment_sum(np.zeros(0), np.zeros(0, np.int64), 2)
+    assert empty.tolist() == [0.0, 0.0]
 
 
 def test_segmented_gaps_respect_boundaries():
@@ -258,14 +268,15 @@ def test_segmented_gaps_respect_boundaries():
 
 
 # --------------------------------------------------------------------------
-# backend-neutral kernel: the numpy instantiation must also match
+# backend-neutral kernel: the numpy instantiation against the oracle
 # --------------------------------------------------------------------------
 
 def test_backend_neutral_kernel_numpy_instantiation():
-    """The ISSUE-4 kernel is backend-neutral; instantiated with the
-    numpy backend (loop vmap, bincount segment_sum — the path the jax
-    program mirrors) it must reproduce the production numpy plane.
-    This keeps NumpyBackend an exercised oracle, not dead code."""
+    """The sweep kernel is backend-neutral; instantiated with the numpy
+    backend (loop vmap, bincount segment_sum — the path the jax program
+    mirrors) it must reproduce the scalar oracle (``sweep_reference``)
+    on ragged random stacks with an empty workload, including an SA
+    width off the NPUs' native one (the kernel's own width axis)."""
     from repro.core.backend import get_backend
     from repro.core.policies import _evaluate_batch_backend
     rng = np.random.default_rng(SEED_BACKEND)
@@ -273,12 +284,38 @@ def test_backend_neutral_kernel_numpy_instantiation():
            _random_workload(rng, 2)]
     grid = (PolicyKnobs(), PolicyKnobs(delay_scale=2.0),
             PolicyKnobs(leak_off_logic=0.2, leak_sram_sleep=0.4,
-                        leak_sram_off=0.02))
+                        leak_sram_off=0.02),
+            PolicyKnobs(sa_width=256))
     npus = (get_npu("NPU-B"), get_npu("NPU-E"))
-    ref = evaluate_batch(wls, npus, POLICIES, grid)
+    ref = sweep_reference(wls, npus, POLICIES, grid)
     got = _evaluate_batch_backend(wls, npus, POLICIES, grid,
                                   get_backend("numpy"))
-    _assert_records_match(ref.records(), got.records())
+    _assert_records_match(ref, got.records())
+
+
+# A thinned §6.5 sensitivity grid: both ends of every axis, with the
+# native delay between the extremes (3 x 2 x 2 x 2 = 24 knob points).
+FINE_AXES = dict(delay_scale=(0.25, 1.0, 8.0),
+                 leak_off_logic=(0.01, 0.4),
+                 leak_sram_sleep=(0.1, 0.6),
+                 leak_sram_off=(0.002, 0.02))
+
+
+def test_sweep_grid_fine_axes_match_reference():
+    """``sweep_grid`` on the host (the kernel's numpy instantiation)
+    against the scalar oracle over the §6.5 axes' extremes, for one
+    workload of every suite family (train, prefill, decode, DLRM, DiT)
+    on two NPU generations: the records and their delay-major knob
+    order agree to 1e-9."""
+    from repro.core.sweep import knob_product, sweep_grid
+    suite = paper_suite()
+    wls = [suite[i] for i in (0, 4, 8, 12, 15)]
+    npus = ("NPU-A", "NPU-E")
+    got = sweep_grid(wls, npus, POLICIES, backend="numpy", **FINE_AXES)
+    ref = sweep_reference(wls, npus, POLICIES, knob_product(**FINE_AXES))
+    assert len(got) == len(wls) * len(npus) * len(POLICIES) * 24
+    _assert_records_match(ref, got)
+
 
 def test_evaluate_all_matches_evaluate():
     wl = paper_suite()[8]
