@@ -194,22 +194,27 @@ def fleet_phase(scenario, grid: KnobGrid) -> dict:
 @contextlib.contextmanager
 def _result_devices(bk):
     """Collect, per kernel call, the devices holding the kernel's
-    results: every jax kernel's outputs pass through ``bk.block``. An
-    SPMD program's results sit on the devices it ran across."""
+    results: every jax kernel's outputs pass through ``bk.block`` (the
+    event scan) or ``bk.copy_to_host_async`` (the sweep). An SPMD
+    program's results sit on the devices it ran across."""
     seen: list[frozenset] = []
-    block = bk.block
 
-    def spy(tree):
-        seen.append(frozenset().union(*(
-            leaf.sharding.device_set
-            for leaf in jax.tree_util.tree_leaves(tree))))
-        return block(tree)
+    def spy(fn):
+        def wrapped(tree):
+            seen.append(frozenset().union(*(
+                leaf.sharding.device_set
+                for leaf in jax.tree_util.tree_leaves(tree))))
+            return fn(tree)
+        return wrapped
 
-    bk.block = spy
+    names = ("block", "copy_to_host_async")
+    for name in names:
+        setattr(bk, name, spy(getattr(bk, name)))
     try:
         yield seen
     finally:
-        del bk.block
+        for name in names:
+            delattr(bk, name)
 
 
 def _require_span(seen: list, mesh, what: str) -> None:

@@ -25,6 +25,10 @@ The contract each backend provides:
   program-plane event kernel's spine (``repro.core.program_plane``);
 * ``asarray`` / ``to_numpy`` / ``compute_scope()`` — transfer in/out and
   the dtype discipline scope (jax: float64 via x64);
+* ``copy_to_host_async(x)`` / ``block(tree)`` — start an output's copy
+  to the host without waiting (the sweep launches every NPU's kernel
+  before its first pull), and wait for a result needed at once (no-ops
+  on numpy);
 * ``span(name, counts=None)`` — a context manager around one layer of
   the call path (``regate.*``): on jax a
   ``jax.profiler.TraceAnnotation``, so a running profiler records it on
@@ -229,6 +233,10 @@ class NumpyBackend:
         return tree
 
     @staticmethod
+    def copy_to_host_async(x) -> None:
+        """Nothing to start: the array is on the host already."""
+
+    @staticmethod
     def scan(f, init, xs, length: int):
         """Sequential carry loop (the numpy stand-in for ``lax.scan``).
 
@@ -307,8 +315,16 @@ class JaxBackend:
         return self._jax.vmap(fn)(knobs)
 
     def block(self, tree):
-        """Wait for async dispatch so wall-clock timings are honest."""
+        """Wait for async dispatch where a result is needed at once (the
+        event-scan kernel, whose outputs the host pulls right away)."""
         return self._jax.block_until_ready(tree)
+
+    @staticmethod
+    def copy_to_host_async(x) -> None:
+        """Start the device-to-host copy of ``x`` without waiting, so
+        that a later ``to_numpy`` finds it landed or under way: the
+        sweep's harvest pulls one NPU's slab while the next computes."""
+        x.copy_to_host_async()
 
     def scan(self, f, init, xs, length: int):
         """``lax.scan`` with a carry-only body (no stacked outputs): the

@@ -1495,6 +1495,10 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
     sharded over ``"knob"``, op columns over ``"wl"`` — while a pure
     ``("wl",)`` mesh keeps the GSPMD path (sharded ``device_put`` into
     the ordinary jitted kernel).
+
+    Every NPU's kernel is launched, and its slab's copy to the host
+    started, before the first is harvested, so the host's pulls and
+    assembly of one NPU run while the device computes the next.
     """
     st = stack_traces(workloads)
     policies = tuple(policies)
@@ -1524,7 +1528,10 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
             if "wl" in sizes:
                 wl_axis = "wl"
     with bk.compute_scope():
-        for ai, npu in enumerate(npu_specs):
+        # Launch every NPU's kernel before the first harvest: while the
+        # host pulls and assembles one NPU, the device runs the next.
+        launched = []
+        for npu in npu_specs:
             if knob_axis is not None:
                 data, sram_setpm = _sharded_backend_data(st, npu, bk,
                                                          wl_size)
@@ -1532,8 +1539,7 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                                                 pad_to=knob_size), bk)
                 kern = _shard_kernel(bk, mesh, policies, wl_axis,
                                      knob_axis)
-                with bk.span("regate.sweep_kernel"):
-                    slab = bk.block(kern(data, knobs))
+                args = (data, knobs)
             else:
                 if mesh is None:
                     # one slab per dtype: at most 2 puts, cached
@@ -1548,17 +1554,26 @@ def _evaluate_batch_backend(workloads, npu_specs, policies, knob_grid,
                     data, data_layout = bk.shard_data(data, mesh), None
                 knob_layout, knobs = _knob_arrays(knob_grid, npu, bk)
                 kern = _backend_kernel(bk)
-                with bk.span("regate.sweep_kernel"):
-                    slab = bk.block(kern(data, knobs, policies,
-                                         (data_layout, knob_layout)))
+                args = (data, knobs, policies, (data_layout, knob_layout))
+            # the launch alone: the pull waits for the kernel
+            with bk.span("regate.sweep_kernel"):
+                slab = kern(*args)
+                bk.copy_to_host_async(slab)
+            launched.append((slab, sram_setpm))
 
-            # one pull per NPU: (n_out, K_pad, W), shard padding dropped.
-            # Beside the transfer, the span counts the work of the call
-            # it harvests: the stack's op rows (before any mesh padding),
-            # those with matmul dims, and the knob points.
+        for ai, (npu, (slab, sram_setpm)) in enumerate(
+                zip(npu_specs, launched)):
+            # one pull per NPU: (n_out, K_pad, W), shard padding dropped;
+            # it waits for the kernel if that has not finished. Beside
+            # the transfer, the span counts the work of the call it
+            # harvests: the stack's op rows (before any mesh padding),
+            # those with matmul dims, and the knob points; and the
+            # kernel calls launched and not yet harvested, this one
+            # included.
             with bk.span("regate.harvest", lambda: dict(
                     transfer_counts(slab), ops=st.n_ops,
-                    mm_ops=int(st.has_mm.sum()), knobs=k_n)):
+                    mm_ops=int(st.has_mm.sum()), knobs=k_n,
+                    in_flight=len(launched) - ai)):
                 vm = _unpack(bk.to_numpy(slab)[:, :k_n], policies)
             cells, sram_static, dyn = vm["cells"], vm["sram"], vm["dyn"]
             d_seg, sram_gu, sram_dyn = \
