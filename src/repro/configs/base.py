@@ -49,6 +49,8 @@ class MoEConfig:
     first_dense_layers: int = 0  # leading layers that use a dense MLP instead
     capacity_factor: float = 2.0
     group_size: int = 1024  # GShard dispatch group size (tokens)
+    act: str = "swiglu"  # expert MLP: swiglu (gated, up D->2F) | relu2 (D->F)
+    d_ff_shared: int = 0  # 0 => n_shared_experts * d_ff_expert
 
 
 @dataclass(frozen=True)
@@ -59,11 +61,14 @@ class SSMConfig:
     conv_width: int = 4
     n_groups: int = 1
     chunk: int = 256  # SSD chunk length
+    n_heads: int = 0  # 0 => expand * d_model / head_dim heads
 
     def d_inner(self, d_model: int) -> int:
+        if self.n_heads:
+            return self.n_heads * self.head_dim
         return self.expand * d_model
 
-    def n_heads(self, d_model: int) -> int:
+    def heads(self, d_model: int) -> int:
         return self.d_inner(d_model) // self.head_dim
 
 
@@ -100,6 +105,9 @@ class ArchConfig:
     # hybrid (hymba): sliding-window attention everywhere except global layers
     sliding_window: int = 0  # 0 => full attention
     n_global_layers: int = 0  # leading/middle/trailing full-attention layers
+    # one mixer per block, as published (nemotron_h hybrid_override_pattern):
+    # M Mamba-2, E MoE, * attention; "" => every layer is the family's block
+    layer_pattern: str = ""
     # structure
     encoder_only: bool = False
     tie_embeddings: bool = True

@@ -36,6 +36,8 @@ The contract each backend provides:
   ``counts`` returns as event stats (called only while a profiler
   records); it costs about a microsecond when none runs. On numpy a
   no-op;
+  ``host_span(name)``, a module function, is the same hook for a host
+  layer that runs whatever the backend (``opgen.arch_workload``);
 * ``sa_occupancy(...)`` — the in-program SA PE-occupancy pass: the
   backend-neutral closed form, traced on jax, so SA width rides the
   knob axis;
@@ -66,6 +68,7 @@ from __future__ import annotations
 
 import contextlib
 import math
+import sys
 from typing import Callable, Optional
 
 import numpy as np
@@ -79,6 +82,21 @@ _X64_HELP = (
     "make sure nothing inside it turns x64 off, or enable it for the "
     "whole process with JAX_ENABLE_X64=1."
 )
+
+
+@contextlib.contextmanager
+def host_span(name: str):
+    """The ``regate.*`` span of a host layer that runs whatever the array
+    backend: a ``jax.profiler.TraceAnnotation`` while a profiler records,
+    yielded so that counts known only at the end of the work can be set
+    on it (``set_metadata``); otherwise nothing is opened, ``None`` is
+    yielded and jax is not imported for it."""
+    jax = sys.modules.get("jax")
+    if jax is None or not jax.profiler.TraceAnnotation.is_enabled():
+        yield None
+        return
+    with jax.profiler.TraceAnnotation(name) as ann:
+        yield ann
 
 
 def _tree_stack(items: list):

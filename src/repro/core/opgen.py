@@ -18,6 +18,7 @@ from typing import Optional
 import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeConfig
+from repro.core.backend import host_span
 
 
 @dataclass(frozen=True)
@@ -541,21 +542,36 @@ def diffusion_workload(model: str, *, batch: int = 8, n_chips: int = 4) \
 # Assigned-architecture workloads (execution plane -> power plane bridge)
 # --------------------------------------------------------------------------
 
+def _full_terms(cfg: ArchConfig) -> bool:
+    """Whether ``cfg``'s attention and Mamba-2 mixers carry three terms
+    that the dense and SSM stacks leave out: every sequence's KV cache
+    read in decode (not ``max(1, T // 8)`` of them), each decode token's
+    SSM state read and written, and the B, C and dt columns of the SSM
+    input projection and conv. True for a model traced from its
+    ``layer_pattern``. A temporary compatibility switch: the benchmark's
+    references of the dense and SSM configurations pin the shorter
+    terms, and the switch goes when the terms are corrected together
+    with those references (ROADMAP.md section 3)."""
+    return bool(cfg.layer_pattern)
+
+
 def _attention_ops(cfg: ArchConfig, T: int, kv_len: int, decode: bool,
                    tp: int) -> list[Op]:
     """Grouped-query attention: fused qkv projection, the attention
     itself (prefill on the SA, decode on the VU against the KV cache),
-    and the output projection; heads split ``tp`` ways."""
+    and the output projection; heads split ``tp`` ways. Decode reads
+    the KV cache of every one of the ``T`` sequences, or of
+    ``max(1, T // 8)`` of them without ``_full_terms``."""
     D = cfg.d_model
     H = max(1, cfg.n_heads)
     hd = max(1, cfg.head_dim)
     ops = [_matmul("qkv", T, D, (H + 2 * cfg.n_kv_heads) * hd // tp)]
     if decode:
+        seqs = T if _full_terms(cfg) else max(1, T // 8)
         ops.append(Op(
             "attn_decode",
             flops_vu=2.0 * T * kv_len * hd * 2 * H / tp,
-            bytes_hbm=kv_len * cfg.n_kv_heads * hd * BF16 * 2
-            * max(1, T // 8) / tp,
+            bytes_hbm=kv_len * cfg.n_kv_heads * hd * BF16 * 2 * seqs / tp,
             sram_demand=8 << 20))
     else:
         ops.append(Op(
@@ -564,6 +580,39 @@ def _attention_ops(cfg: ArchConfig, T: int, kv_len: int, decode: bool,
             matmul_dims=(T, hd, kv_len), sram_demand=24 << 20))
     ops.append(_matmul("out_proj", T, H * hd // tp, D))
     return ops
+
+
+def _ssm_ops(cfg: ArchConfig, T: int, decode: bool, tp: int) -> list[Op]:
+    """A Mamba-2 SSD mixer (arXiv:2405.21060) for ``T`` tokens, heads
+    split ``tp`` ways: the input projection, the causal conv and its
+    activation, the chunked scan (decode: one state update per token on
+    the VU) and the output projection.
+
+    With ``_full_terms`` the published layout is traced whole: the
+    projection also emits the B and C groups and one dt a head, the conv
+    runs over x, B and C, and each decode token reads and writes its
+    sequence's (heads, head_dim, d_state) state. Without it the block
+    keeps the x and z columns alone and no state traffic."""
+    ss, D = cfg.ssm, cfg.d_model
+    di, nh = ss.d_inner(D), ss.heads(D)
+    proj, conv = 2 * di, di
+    ssd_bytes = T * di * BF16 * 3 / tp
+    if _full_terms(cfg):
+        bc = 2 * ss.n_groups * ss.d_state
+        proj, conv = 2 * di + bc + nh, di + bc
+        if decode:
+            ssd_bytes += T * nh * ss.head_dim * ss.d_state * BF16 * 2 / tp
+    return [
+        _matmul("in_proj", T, D, proj // tp),
+        _vector("conv+act", T * conv / tp, flops_per_elem=10),
+        Op("ssd", flops_vu=T * nh * ss.head_dim * ss.d_state * 6 / tp,
+           flops_sa=(0 if decode else
+                     2.0 * T * ss.chunk * ss.head_dim * nh * 2 / tp),
+           bytes_hbm=ssd_bytes,
+           matmul_dims=None if decode else (T, ss.head_dim, ss.chunk),
+           sram_demand=16 << 20),
+        _matmul("out_proj", T, di // tp, D),
+    ]
 
 
 def _mla_ops(cfg: ArchConfig, T: int, kv_len: int, decode: bool,
@@ -611,21 +660,39 @@ def _mla_ops(cfg: ArchConfig, T: int, kv_len: int, decode: bool,
     return ops
 
 
-def _routed_experts(R: int, E: int, D: int, F: int) -> list[Op]:
+def _expert_mlp(prefix: str, rows: int, D: int, F: int, act: str, *,
+                split: int = 1, count: int = 1) -> list[Op]:
+    """One expert MLP of width ``F``, split ``split`` ways, at M =
+    ``rows``: the up GEMM (gate and up, D -> 2F, for SwiGLU; D -> F for
+    the non-gated relu² of Nemotron-H), the activation on the VU, fused
+    with the GEMM's output, and the down GEMM."""
+    if act == "swiglu":
+        up, flops = 2 * F // split, 3
+    elif act == "relu2":
+        up, flops = F // split, 2
+    else:
+        raise ValueError(f"unknown expert activation {act!r}; have "
+                         f"'swiglu', 'relu2'")
+    return [_matmul(f"{prefix}_up", rows, D, up, count=count),
+            _vector(f"{prefix}_{act}", rows * F / split,
+                    flops_per_elem=flops, bytes_per_elem=0.5, count=count),
+            _matmul(f"{prefix}_down", rows, F // split, D, count=count)]
+
+
+def _routed_experts(R: int, E: int, D: int, F: int,
+                    act: str = "swiglu") -> list[Op]:
     """The ``E`` routed experts a chip holds, fed ``R`` token slots
     spread as evenly as integers allow over ``min(E, R)`` of them. Each
-    expert's up GEMM, SwiGLU and down GEMM is priced at its own M; the
-    experts that share an M are one op's ``count``, so there are at most
-    two ops per GEMM, and every active expert's weights stream once."""
+    expert's up GEMM, activation and down GEMM is priced at its own M;
+    the experts that share an M are one op's ``count``, so there are at
+    most two ops per GEMM, and every active expert's weights stream
+    once."""
     n = min(E, R)
     m, extra = divmod(R, n)
     ops: list[Op] = []
     for rows, k in ((m + 1, extra), (m, n - extra)):
         if k:
-            ops += [_matmul("expert_up", rows, D, 2 * F, count=k),
-                    _vector("expert_swiglu", rows * F, flops_per_elem=3,
-                            bytes_per_elem=0.5, count=k),
-                    _matmul("expert_down", rows, F, D, count=k)]
+            ops += _expert_mlp("expert", rows, D, F, act, count=k)
     return ops
 
 
@@ -637,7 +704,9 @@ def _deepseek_moe_ops(mo, T: int, D: int, *, n_chips: int,
     are routed over all experts, sent once per chosen expert to the
     expert's chip and combined back (all-to-all), with balanced routing
     and no token dropped. The shared experts run every token, split
-    ``tp`` ways."""
+    ``tp`` ways, at ``d_ff_shared`` where it is given (else
+    ``n_shared_experts`` x ``d_ff_expert``); every expert MLP is gated
+    (SwiGLU) or not (relu², Nemotron-H) as ``mo.act`` says."""
     ep = math.gcd(mo.n_experts, n_chips)
     T_m = max(1, T // tp)
     R = T_m * mo.top_k
@@ -645,15 +714,17 @@ def _deepseek_moe_ops(mo, T: int, D: int, *, n_chips: int,
     ops = [_matmul("router", T_m, D, mo.n_experts),
            _vector("router_topk", T_m * mo.n_experts, flops_per_elem=4),
            _collective("a2a_dispatch", a2a)]
-    ops += _routed_experts(R, mo.n_experts // ep, D, mo.d_ff_expert)
+    ops += _routed_experts(R, mo.n_experts // ep, D, mo.d_ff_expert,
+                           mo.act)
     ops.append(_collective("a2a_combine", a2a))
     if mo.n_shared_experts:
-        fs = mo.n_shared_experts * mo.d_ff_expert
-        ops += [_matmul("shared_up", T, D, 2 * fs // tp),
-                _vector("shared_swiglu", T * fs / tp, flops_per_elem=3,
-                        bytes_per_elem=0.5),
-                _matmul("shared_down", T, fs // tp, D)]
+        fs = mo.d_ff_shared or mo.n_shared_experts * mo.d_ff_expert
+        ops += _expert_mlp("shared", T, D, fs, mo.act, split=tp)
     return ops
+
+
+# the op rows each kind of mixer emitted, as ``arch_workload``'s span counts
+_MIXER_COUNT = {"M": "ssm_ops", "*": "attn_ops", "E": "moe_ops"}
 
 
 def arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int = 256,
@@ -663,9 +734,27 @@ def arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int = 256,
     Used when HLO statistics are not available (and cross-checked against
     the dry-run numbers in the benchmarks). Attention is latent (MLA)
     where ``cfg.mla`` is set; with ``cfg.moe`` every layer after the
-    leading dense ones runs expert-parallel DeepSeekMoE.
+    leading dense ones runs expert-parallel DeepSeekMoE. A model with a
+    ``layer_pattern`` is traced block by block: one mixer a block (``M``
+    Mamba-2, ``E`` MoE, ``*`` attention), each followed by the layer
+    tail.
+
+    Opens the host span ``regate.arch_workload`` (``backend.host_span``)
+    with the counts ``layers``, ``ops`` and the rows each kind of mixer
+    emitted (``ssm_ops``, ``attn_ops``, ``moe_ops``; a tail, a dense MLP
+    and the head count in ``ops`` alone).
     """
+    with host_span("regate.arch_workload") as ann:
+        wl, counts = _arch_workload(cfg, shape, n_chips=n_chips, tp=tp)
+        if ann is not None:
+            ann.set_metadata(layers=cfg.n_layers, ops=len(wl.ops), **counts)
+    return wl
+
+
+def _arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int,
+                   tp: int) -> tuple[Workload, dict]:
     ops: list[Op] = []
+    counts = dict.fromkeys(_MIXER_COUNT.values(), 0)
     decode = shape.kind == "decode"
     B, S = shape.global_batch, shape.seq_len
     dp = max(1, n_chips // tp)
@@ -683,45 +772,58 @@ def arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int = 256,
                    for o in ops_layer]
         ops.extend(seq_ops * L)
 
-    if cfg.family == "ssm":
-        ss = cfg.ssm
-        di = ss.d_inner(D)
-        nh = ss.n_heads(D)
-        layer = [
-            _matmul("in_proj", T, D, 2 * di // tp),
-            _vector("conv+act", T * di / tp, flops_per_elem=10),
-            Op("ssd", flops_vu=T * nh * ss.head_dim * ss.d_state * 6 / tp,
-               flops_sa=(0 if decode else
-                         2.0 * T * ss.chunk * ss.head_dim * nh * 2 / tp),
-               bytes_hbm=T * di * BF16 * 3 / tp,
-               matmul_dims=None if decode else (T, ss.head_dim, ss.chunk),
-               sram_demand=16 << 20),
-            _matmul("out_proj", T, di // tp, D),
-        ]
+    tail = []
+    if tp > 1:
+        tail.append(_collective("ar_layer",
+                                2 * T * D * BF16 * (tp - 1) / tp))
+    tail.append(_vector("norms", T * D, flops_per_elem=8))
+
+    if cfg.layer_pattern:
+        unknown = sorted(set(cfg.layer_pattern) - set(_MIXER_COUNT))
+        if unknown:
+            raise ValueError(f"{cfg.name}: layer_pattern letters {unknown} "
+                             f"are none of M (Mamba-2), E (MoE), * "
+                             f"(attention)")
+        if len(cfg.layer_pattern) != cfg.n_layers:
+            raise ValueError(f"{cfg.name}: layer_pattern has "
+                             f"{len(cfg.layer_pattern)} blocks, n_layers "
+                             f"{cfg.n_layers}")
+        if train:
+            raise ValueError(f"{cfg.name}: a train step of a layer_pattern "
+                             f"model is not traced (no parameter count)")
+        build = {"M": lambda: _ssm_ops(cfg, T, decode, tp),
+                 "*": lambda: _attention_ops(cfg, T, kv_len, decode, tp),
+                 "E": lambda: _deepseek_moe_ops(cfg.moe, T, D,
+                                                n_chips=n_chips, tp=tp)}
+        mixers: dict[str, list[Op]] = {}
+        for letter in cfg.layer_pattern:
+            if letter not in mixers:
+                mixers[letter] = build[letter]()
+            ops += mixers[letter] + tail   # no train: the ops as built
+            counts[_MIXER_COUNT[letter]] += len(mixers[letter])
+    elif cfg.family == "ssm":
+        layer = _ssm_ops(cfg, T, decode, tp)
         add_layer(layer, cfg.n_layers)
+        counts["ssm_ops"] = len(layer) * cfg.n_layers
     else:
         attend = _mla_ops if cfg.mla else _attention_ops
         attn = attend(cfg, T, kv_len, decode, tp)
+        counts["attn_ops"] = len(attn) * cfg.n_layers
         mlp = []
         if cfg.d_ff:
             mlp = [_matmul("mlp_up", T, D, 2 * cfg.d_ff // tp),
                    _matmul("mlp_down", T, cfg.d_ff // tp, D)]
-        tail = []
-        if tp > 1:
-            tail.append(_collective("ar_layer",
-                                    2 * T * D * BF16 * (tp - 1) / tp))
-        tail.append(_vector("norms", T * D, flops_per_elem=8))
         if cfg.moe:
             # leading dense layers keep the dense MLP at d_ff
             lead = cfg.moe.first_dense_layers
             add_layer(attn + mlp + tail, lead)
             moe = _deepseek_moe_ops(cfg.moe, T, D, n_chips=n_chips, tp=tp)
             add_layer(attn + moe + tail, cfg.n_layers - lead)
+            counts["moe_ops"] = len(moe) * (cfg.n_layers - lead)
         else:
             add_layer(attn + mlp + tail, cfg.n_layers)
 
-    ops.append(_matmul("lm_head", T if not train else T,
-                       D, cfg.vocab_padded // tp))
+    ops.append(_matmul("lm_head", T, D, cfg.vocab_padded // tp))
     if train:
         from repro.models.registry import count_params
         n_params = count_params(cfg)
@@ -729,8 +831,8 @@ def arch_workload(cfg: ArchConfig, shape: ShapeConfig, *, n_chips: int = 256,
                                2 * n_params * BF16 / (tp * dp)))
         ops.append(_vector("adam", n_params / (tp * dp), flops_per_elem=12,
                            bytes_per_elem=16))
-    return Workload(f"{cfg.name}-{shape.name}", shape.kind, tuple(ops),
-                    n_chips=n_chips)
+    return (Workload(f"{cfg.name}-{shape.name}", shape.kind, tuple(ops),
+                     n_chips=n_chips), counts)
 
 
 # --------------------------------------------------------------------------

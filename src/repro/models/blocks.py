@@ -466,7 +466,7 @@ def ssd_specs(cfg: ArchConfig) -> dict:
     ss = cfg.ssm
     D = cfg.d_model
     di = ss.d_inner(D)
-    nh = ss.n_heads(D)
+    nh = ss.heads(D)
     GN = ss.n_groups * ss.d_state
     w = ss.conv_width
 
@@ -578,7 +578,7 @@ def _ssd_inputs(p, h, cfg: ArchConfig, conv_state=None):
     """
     ss = cfg.ssm
     D = cfg.d_model
-    di, nh = ss.d_inner(D), ss.n_heads(D)
+    di, nh = ss.d_inner(D), ss.heads(D)
     GN = ss.n_groups * ss.d_state
     B_, S, _ = h.shape
 

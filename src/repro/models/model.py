@@ -300,7 +300,7 @@ def mla_cache_struct(cfg, B, S, dtype):
 def ssd_cache_struct(cfg, B, dtype):
     ss = cfg.ssm
     di = ss.d_inner(cfg.d_model)
-    nh = ss.n_heads(cfg.d_model)
+    nh = ss.heads(cfg.d_model)
     GN = ss.n_groups * ss.d_state
     W = ss.conv_width
     conv = (jax.ShapeDtypeStruct((B, W - 1, di), dtype),
